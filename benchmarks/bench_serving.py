@@ -37,6 +37,7 @@ import numpy as np
 from benchmarks.common import emit
 from repro import plan as plan_mod
 from repro.core import soft_rank, soft_sort
+from repro.launch.compile_cache import compile_cache_off
 from repro.obs import artifacts as obs_artifacts
 from repro.obs import metrics
 from repro.obs.timing import percentiles
@@ -123,9 +124,12 @@ def run(smoke: bool = False, out_path: str = "BENCH_serving.json") -> dict:
                      queue_capacity=max(num_requests, 256))
   engine = ServingEngine(cfg)
 
-  t0 = time.perf_counter()
-  compiled = engine.warmup()
-  warmup_us = (time.perf_counter() - t0) * 1e6
+  # The warmup and the cold pass measure compilation: a persistent cache
+  # populated by an earlier run must not answer for it.
+  with compile_cache_off():
+    t0 = time.perf_counter()
+    compiled = engine.warmup()
+    warmup_us = (time.perf_counter() - t0) * 1e6
   emit(f"serving/warmup/buckets={len(engine.policy.sizes)}"
        f"x{len(engine.policy.row_sizes)}", warmup_us,
        f"{compiled} executables AOT-compiled", collect=False)
@@ -170,7 +174,8 @@ def run(smoke: bool = False, out_path: str = "BENCH_serving.json") -> dict:
 
   # Per-request jit baselines over the identical stream.
   _baseline_fn.cache_clear()
-  cold_wall, _ = _per_request_pass(requests)
+  with compile_cache_off():
+    cold_wall, _ = _per_request_pass(requests)
   warm_wall, warm_lat = _per_request_pass(requests)
   wp50, wp95, wp99 = percentiles(warm_lat)
   cold_rps = len(requests) / max(cold_wall, 1e-9)

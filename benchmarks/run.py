@@ -50,6 +50,7 @@ from benchmarks import (
     common,
 )
 from repro import plan as plan_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import artifacts as obs_artifacts
 from repro.obs import metrics as obs_metrics
 
@@ -74,6 +75,7 @@ def main() -> None:
                   help="tiny backend sweep + depth curve only; still writes "
                        "BENCH_*.json")
   args = ap.parse_args()
+  enable_compile_cache()
 
   # Start each harness invocation from a clean registry so artifact metrics
   # describe exactly this run, not whatever imported us earlier.

@@ -128,6 +128,26 @@ def registered() -> list[str]:
   return sorted(_REGISTRY)
 
 
+def parse_overrides(pairs):
+  """``["key=value", ...]`` (the ``--set`` flags) -> ``ArchConfig`` overrides,
+  with values cast to int, float or bool where they parse as one."""
+  out = {}
+  for pair in pairs or []:
+    k, v = pair.split("=", 1)
+    for cast in (int, float):
+      try:
+        out[k] = cast(v)
+        break
+      except ValueError:
+        continue
+    else:
+      if v in ("True", "False"):
+        out[k] = v == "True"
+      else:
+        out[k] = v
+  return out
+
+
 def all_assigned() -> list[str]:
   """The 10 assigned architectures (import side-effect registers them)."""
   names = [

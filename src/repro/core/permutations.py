@@ -108,7 +108,7 @@ def _packed_sort_u64(hi: Array, lo: Array) -> tuple[Array, Array]:
   ``jnp.uint64(32)`` shift amount would miscompile even inside an
   ``enable_x64`` trace scope.
   """
-  with jax.experimental.enable_x64(True):
+  with jax.enable_x64(True):
     packed = lax.bitcast_convert_type(jnp.stack([lo, hi], axis=-1),
                                       jnp.uint64)
     skeys = lax.sort(packed, dimension=-1, is_stable=False)

@@ -391,10 +391,24 @@ def projection_permutahedron(
     # packed integer sort keys assume f32, so the whole projection runs
     # promoted and only the result is demoted.
     out = _dispatch.dispatch_projection(
-        z.astype(jnp.float32), w.astype(jnp.float32), regularization, impl,
-        path, plan=plan, z_is_sorted=z_is_sorted, w_is_sorted=w_is_sorted,
-        z_perm=z_perm, w_perm=w_perm)
+        _shift_row_max_to_zero(z.astype(jnp.float32)), w.astype(jnp.float32),
+        regularization, impl, path, plan=plan, z_is_sorted=z_is_sorted,
+        w_is_sorted=w_is_sorted, z_perm=z_perm, w_perm=w_perm)
     return out.astype(dtype)
   return _dispatch.dispatch_projection(
-      z, w, regularization, impl, path, plan=plan, z_is_sorted=z_is_sorted,
-      w_is_sorted=w_is_sorted, z_perm=z_perm, w_perm=w_perm)
+      _shift_row_max_to_zero(z), w, regularization, impl, path, plan=plan,
+      z_is_sorted=z_is_sorted, w_is_sorted=w_is_sorted, z_perm=z_perm,
+      w_perm=w_perm)
+
+
+def _shift_row_max_to_zero(z: Array) -> Array:
+  """z minus its row max, held out of the gradient.
+
+  Exact: every point of a permutahedron has the same coordinate sum, so
+  both projections are unchanged when one constant is added to a whole
+  row of z (and so is their Jacobian).  It keeps ``out = z - v`` from
+  cancelling in float32 when |z| is large, as ``values / eps`` is at
+  small eps.  Padding below a row's real entries leaves its max alone,
+  so the serving padding stays exact.
+  """
+  return z - lax.stop_gradient(jnp.max(z, axis=-1, keepdims=True))

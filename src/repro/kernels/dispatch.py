@@ -21,8 +21,9 @@ Forward backends
                  log2(n) vectorized merge levels — O(n log n) work at
                  O(log n) depth, the paper's complexity claim realized on
                  depth-dominated hardware (CPU/GPU).
-* ``"pallas"``   tiled TPU kernel (``repro.kernels.pav``); interpret mode
-                 off-TPU, so it is usable (slowly) everywhere.
+* ``"pallas"``   tiled kernel (``repro.kernels.pav``); interpret mode
+                 off-TPU.  It does not compile for TPU v5e, so no plan
+                 routes to it: only an explicit ``impl="pallas"`` does.
 * ``"minimax"``  O(n^2) vectorized closed form (``repro.kernels.ref``) with
                  zero data-dependent control flow — the right trade for
                  small n and under SPMD.
@@ -44,7 +45,7 @@ backend, backward backend, projection path)::
         ``set_active_plan`` plan)
       > packaged default plan (src/repro/plan/default_plan.json,
         emitted by tools/autotune.py from measured BENCH sweeps)
-      > built-in plan (repro.plan.builtin_plan: TPU -> pallas, small-n
+      > built-in plan (repro.plan.builtin_plan: TPU -> scan, small-n
         minimax under a memory cap, scan otherwise; segscan; fused)
 
 ``"auto"`` — as an argument or environment value — means "fall through

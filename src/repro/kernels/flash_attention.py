@@ -54,10 +54,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *,
 
   def body(j, carry):
     m, l, acc = carry
-    k_blk = pl.load(k_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
-    v_blk = pl.load(v_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
+    k_blk = k_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
+    v_blk = v_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
     s = jnp.einsum("gqd,kd->gqk", q, k_blk)           # (G, bq, bkv)
     if causal:
       kv_pos = j * block_kv + jnp.arange(block_kv)

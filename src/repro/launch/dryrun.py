@@ -30,7 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis.hlo import analyze_text
 from repro.analysis.roofline import (
     count_active_params, model_flops, roofline_terms)
-from repro.configs.base import all_assigned, get_config
+from repro.configs.base import all_assigned, get_config, parse_overrides
 from repro.launch import shapes as SH
 from repro.launch import steps as ST
 from repro.launch.mesh import data_axes_of, make_production_mesh
@@ -45,24 +45,6 @@ DEFAULT_OUT = os.path.join(os.path.dirname(__file__),
 def _named(mesh, spec_tree):
   return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                       is_leaf=lambda x: isinstance(x, P))
-
-
-def parse_overrides(pairs):
-  out = {}
-  for pair in pairs or []:
-    k, v = pair.split("=", 1)
-    for cast in (int, float):
-      try:
-        out[k] = cast(v)
-        break
-      except ValueError:
-        continue
-    else:
-      if v in ("True", "False"):
-        out[k] = v == "True"
-      else:
-        out[k] = v
-  return out
 
 
 def lower_cell(cfg, cell, mesh):

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import plan as repro_plan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import artifacts as obs_artifacts
 from repro.obs.tracing import trace_annotation
 
@@ -104,10 +105,9 @@ def run_engine(args) -> None:
 
 
 def run_lm(args) -> None:
-  from repro.configs.base import get_config
+  from repro.configs.base import get_config, parse_overrides
   from repro.data.pipeline import pipeline_for_arch
   from repro.launch import steps as ST
-  from repro.launch.dryrun import parse_overrides
   from repro.models import transformer as T
 
   if args.smoke:
@@ -212,6 +212,7 @@ def main():
   ap.add_argument("--impl", default=None,
                   help="pin the isotonic backend for --engine mode")
   args = ap.parse_args()
+  enable_compile_cache()
 
   if args.plan:
     repro_plan.set_active_plan(repro_plan.load_plan(args.plan))

@@ -37,10 +37,10 @@ import numpy as np
 
 from repro import plan as repro_plan
 from repro.checkpoint import checkpointer as ckpt
-from repro.configs.base import get_config
+from repro.configs.base import get_config, parse_overrides
 from repro.data.pipeline import pipeline_for_arch
 from repro.launch import steps as ST
-from repro.launch.dryrun import parse_overrides
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs import artifacts as obs_artifacts
 from repro.obs import metrics as obs_metrics
@@ -73,9 +73,13 @@ class Trainer:
     self.total_steps = total_steps
     sched = lambda s: cosine_with_warmup(
         s, warmup=min(100, total_steps // 10 + 1), total=total_steps)
+    # Params and optimizer state are donated: the step updates them in
+    # place instead of holding the old and the new copies at once.
     self.train_step = jax.jit(ST.make_train_step(
-        cfg, opt_cfg, lr_schedule=sched, compress_grads=compress_grads))
+        cfg, opt_cfg, lr_schedule=sched, compress_grads=compress_grads),
+        donate_argnums=(0, 1))
     self.compress_grads = compress_grads
+    self.seed = seed
     self._preempted = False
     self._step_times: list[float] = []
     self.straggler_factor = 2.0
@@ -84,7 +88,7 @@ class Trainer:
   # -- lifecycle ----------------------------------------------------------
 
   def init_or_restore(self) -> TrainerState:
-    key = jax.random.PRNGKey(0)
+    key = jax.random.PRNGKey(self.seed)
     params = T.init_params(self.cfg, key)
     opt_state = ST.init_opt_state(self.cfg, self.opt_cfg, params,
                                   compress_grads=self.compress_grads)
@@ -189,6 +193,7 @@ def main():
                        "active plan for every dispatch decision")
   ap.add_argument("--set", action="append", dest="overrides")
   args = ap.parse_args()
+  enable_compile_cache()
 
   if args.plan:
     repro_plan.set_active_plan(repro_plan.load_plan(args.plan))

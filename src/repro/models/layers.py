@@ -116,7 +116,11 @@ def _attend_block(q, k, v, mask, scale, softcap):
   if softcap > 0.0:
     s = jnp.tanh(s / softcap) * softcap
   s = jnp.where(mask[None, None, None], s, _NEG_INF)
-  m = jnp.max(s, axis=-1)                           # (B,Hkv,G,cq)
+  # The running max only keeps exp in range: the output does not depend on
+  # it, so it carries no gradient.  Differentiating max instead divides by
+  # the count of entries equal to it, which XLA:TPU can make 0 when the
+  # backward pass recomputes s with different rounding (NaN grads).
+  m = lax.stop_gradient(jnp.max(s, axis=-1))        # (B,Hkv,G,cq)
   p = jnp.exp(s - m[..., None])
   l = jnp.sum(p, axis=-1)
   o = jnp.einsum("bhgqk,bkhd->bhgqd", p.astype(v.dtype), v)

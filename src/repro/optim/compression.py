@@ -61,13 +61,11 @@ def init_residual(grads_shape: Any) -> Any:
 
 def pod_psum_int8(x: Array, mesh, spec: P) -> Array:
   """All-reduce over the 'pod' axis with int8 wire format (shard_map)."""
-  from jax.experimental.shard_map import shard_map
-
   def body(local):
     q, scale = _quant_int8(local)
     # Sum dequantized shards; scales are per-pod so psum the decoded value.
     dec = _dequant(q, scale)
     return jax.lax.psum(dec, "pod").astype(local.dtype)
 
-  return shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                   check_rep=False)(x)
+  return jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                       check_vma=False)(x)

@@ -31,7 +31,7 @@ The *active* plan is installed per-process (:func:`set_active_plan`, the
 by ``tools/autotune.py`` from measured ``BENCH_*.json`` sweeps (every
 rule carries the timing-row names that justify it — validated in CI by
 ``tools/check_backends.py --plan``); the *built-in* plan is the
-shape-oblivious safety net (TPU -> pallas, small-n -> minimax under a
+shape-oblivious safety net (TPU -> scan, small-n -> minimax under a
 memory cap, otherwise scan; segscan backward; fused projection) and is
 total — some rule always matches.
 
@@ -271,10 +271,11 @@ def load_plan(path: str) -> ExecutionPlan:
 def builtin_plan() -> ExecutionPlan:
   """The constants-derived fallback plan, matching every possible query.
 
-  Encodes the pre-plan ``auto`` behavior: TPU -> ``pallas``; off-TPU the
-  O(n^2) ``minimax`` closed form for small n under its memory cap; the
-  log-depth ``scan`` machine otherwise (including all shapeless queries);
-  ``segscan`` backward; ``fused`` projection.
+  TPU -> the log-depth ``scan`` machine at every shape (the ``pallas``
+  PAV kernel does not compile for v5e and is reachable only through an
+  explicit ``impl="pallas"``); off-TPU the O(n^2) ``minimax`` closed form
+  for small n under its memory cap, ``scan`` otherwise (including all
+  shapeless queries); ``segscan`` backward; ``fused`` projection.
   """
   return _BUILTIN
 
@@ -282,7 +283,7 @@ def builtin_plan() -> ExecutionPlan:
 _BUILTIN = ExecutionPlan(
     name="builtin",
     rules=(
-        PlanRule("forward", "pallas", op="isotonic", platform="tpu"),
+        PlanRule("forward", "scan", op="isotonic", platform="tpu"),
         PlanRule("forward", "minimax", op="isotonic",
                  max_n=BUILTIN_MINIMAX_MAX_N,
                  max_elems=BUILTIN_MINIMAX_MAX_ELEMS),
@@ -405,18 +406,22 @@ def _governing_plans(plan: ExecutionPlan | None) -> tuple[ExecutionPlan, ...]:
   return tuple(p for p in chain if p is not None)
 
 
-def shape_breakpoints(plan: ExecutionPlan | None = None) -> tuple[int, ...]:
+def shape_breakpoints(plan: ExecutionPlan | None = None, *,
+                      platform: str) -> tuple[int, ...]:
   """Sorted unique n-edges at which some rule's applicability flips.
 
-  For every shape-constrained rule in the governing plan chain, the
-  inclusive bounds ``max_n`` and ``min_n - 1`` are bucket edges: a
-  serving bucket whose width crosses one would pad requests from one
-  backend regime into another.  ``repro.serving.BucketPolicy.from_plan``
-  splices these into its size ladder.
+  For every shape-constrained rule in the governing plan chain that can
+  match on ``platform``, the inclusive bounds ``max_n`` and ``min_n - 1``
+  are bucket edges: a serving bucket whose width crosses one would pad
+  requests from one backend regime into another.
+  ``repro.serving.BucketPolicy.from_plan`` splices these into its size
+  ladder.
   """
   edges: set[int] = set()
   for candidate in _governing_plans(plan):
     for rule in candidate.rules:
+      if rule.platform not in ("*", platform):
+        continue
       if rule.max_n is not None:
         edges.add(rule.max_n)
       if rule.min_n is not None and rule.min_n > 1:

@@ -56,15 +56,16 @@ class BucketPolicy:
                row_sizes=_pow2_ladder(1, max_batch))
 
   @classmethod
-  def from_plan(cls, plan=None, *, min_n: int = 64, max_n: int = 4096,
-                max_batch: int = 64) -> "BucketPolicy":
+  def from_plan(cls, plan=None, *, platform: str, min_n: int = 64,
+                max_n: int = 4096, max_batch: int = 64) -> "BucketPolicy":
     """pow2 ladder refined with the plan chain's n-breakpoints.
 
     ``plan=None`` uses whatever plan currently governs dispatch (active >
-    packaged default > builtin), mirroring the resolution chain.
+    packaged default > builtin), mirroring the resolution chain.  Only
+    edges of rules that can match on ``platform`` count.
     """
     base = set(_pow2_ladder(min_n, max_n))
-    for edge in plan_mod.shape_breakpoints(plan):
+    for edge in plan_mod.shape_breakpoints(plan, platform=platform):
       if min_n <= edge <= max_n:
         base.add(edge)
     sizes = tuple(sorted(base))

@@ -23,6 +23,7 @@ backpressure (the benchmark's throughput loop).
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import threading
 import time
@@ -56,6 +57,11 @@ DTYPE = "float32"
 
 #: Default extras for pad rows (true_n=1, eps=1): valid for every op.
 _EXTRA_DEFAULTS = {"k": 1, "trim": 0}
+
+#: Threads that compile warmup cells (XLA releases the GIL while it
+#: compiles).  On a 13-core TPU v5e host, 6 warmed 126 cells in 243.5 s
+#: and 8 in 290.9 s, one run each.
+COMPILE_WORKERS = 6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,7 +101,7 @@ class ServingEngine:
     if self.config.use_plan_buckets:
       self.policy = BucketPolicy.from_plan(
           plan, min_n=self.config.min_bucket, max_n=self.config.max_bucket,
-          max_batch=self.config.max_batch)
+          max_batch=self.config.max_batch, platform=jax.default_backend())
     else:
       self.policy = BucketPolicy.pow2(
           self.config.min_bucket, self.config.max_bucket,
@@ -140,11 +146,12 @@ class ServingEngine:
       structs.append(jax.ShapeDtypeStruct(shape, np.dtype(dtype)))
     return structs
 
+  def _lower(self, spec: OpSpec, rows: int, bucket_n: int):
+    fn = jax.jit(bound_op(spec.key, self.config.impl, self.plan))
+    return fn.lower(*self._arg_structs(spec, rows, bucket_n))
+
   def _builder(self, spec: OpSpec, rows: int, bucket_n: int):
-    def build():
-      fn = jax.jit(bound_op(spec.key, self.config.impl, self.plan))
-      return fn.lower(*self._arg_structs(spec, rows, bucket_n)).compile()
-    return build
+    return lambda: self._lower(spec, rows, bucket_n).compile()
 
   def warmup(self, ops: Sequence[str] | None = None,
              sizes: Sequence[int] | None = None,
@@ -154,17 +161,21 @@ class ServingEngine:
     Enumeration comes from the bucket policy, which itself derives from
     the governing ExecutionPlan (``BucketPolicy.from_plan``) — so a
     plan-covered request stream hits zero ``aot_cache_miss`` afterwards.
+    Cells are traced in this thread and compiled by a pool of threads,
+    since XLA compiles release the GIL.
     Returns the number of fresh compiles.
     """
-    compiled = 0
-    for key in (ops or self.config.ops):
-      spec = padded_op(key)
-      for bucket_n in (sizes or self.policy.sizes):
-        for rows in (row_sizes or self.policy.row_sizes):
-          if self.cache.warm(self._cell_key(spec, rows, bucket_n),
-                             self._builder(spec, rows, bucket_n)):
-            compiled += 1
-    return compiled
+    pending = []
+    with concurrent.futures.ThreadPoolExecutor(COMPILE_WORKERS) as pool:
+      for key in (ops or self.config.ops):
+        spec = padded_op(key)
+        for bucket_n in (sizes or self.policy.sizes):
+          for rows in (row_sizes or self.policy.row_sizes):
+            cell = self._cell_key(spec, rows, bucket_n)
+            if cell not in self.cache:
+              lowered = self._lower(spec, rows, bucket_n)
+              pending.append((cell, pool.submit(lowered.compile)))
+      return sum(self.cache.warm(cell, fut.result) for cell, fut in pending)
 
   # -- admission ------------------------------------------------------------
 

@@ -37,11 +37,11 @@ def test_auto_resolution_is_deterministic_per_platform():
   # the platform it was measured on (cpu: lax for small-n few-row and
   # huge-n huge-batch cells, scan everywhere in between; see
   # src/repro/plan/default_plan.json) and from the built-in plan
-  # everywhere else (tpu -> pallas; gpu is unmeasured -> builtin chain:
-  # minimax under its small-n cap, scan beyond).
+  # everywhere else (tpu -> scan at every shape; gpu is unmeasured ->
+  # builtin chain: minimax under its small-n cap, scan beyond).
   for platform, shape, want in [
-      ("tpu", (4, 9), "pallas"),
-      ("tpu", (256, 4096), "pallas"),
+      ("tpu", (4, 9), "scan"),
+      ("tpu", (256, 4096), "scan"),
       ("cpu", (4, 9), "lax"),
       ("cpu", (4, D.AUTO_MINIMAX_MAX_N + 1), "lax"),
       ("cpu", (1_000_000, 64), "scan"),
@@ -66,7 +66,7 @@ def test_shapeless_auto_resolution_never_picks_minimax():
     assert D.resolve_backend("isotonic", "l2", None, shape=None,
                              platform=platform) == "scan"
   assert D.resolve_backend("isotonic", "kl", None, shape=None,
-                           platform="tpu") == "pallas"
+                           platform="tpu") == "scan"
 
 
 def test_explicit_backend_wins_over_default():

@@ -223,8 +223,8 @@ if _HAS_HYPOTHESIS:
   floats = st.floats(min_value=-100, max_value=100, allow_nan=False,
                      allow_infinity=False, width=32)
   vectors = st.lists(floats, min_size=1, max_size=BUCKET)
-  eps_strat = st.floats(min_value=1e-3, max_value=1e3, allow_nan=False,
-                        width=32)
+  eps_strat = st.floats(min_value=float(np.float32(1e-3)), max_value=1e3,
+                        allow_nan=False, width=32)
   backend_strat = st.sampled_from(BACKENDS)
   reg_strat = st.sampled_from(REGS)
 

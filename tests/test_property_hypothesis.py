@@ -145,7 +145,7 @@ def _row_batch(data, rows, n, kind):
 def test_scan_backend_matches_lax_l2(data, n, rows, kind, dtype):
   from repro.core.isotonic import isotonic_l2
   x = _row_batch(data, rows, n, kind).astype(dtype)
-  with jax.experimental.enable_x64(dtype == np.float64):
+  with jax.enable_x64(dtype == np.float64):
     a = np.asarray(isotonic_l2(jnp.asarray(x), "scan"))
     b = np.asarray(isotonic_l2(jnp.asarray(x), "lax"))
   np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
@@ -159,7 +159,7 @@ def test_scan_backend_matches_lax_kl(data, n, rows, kind, dtype):
   from repro.core.isotonic import isotonic_kl
   s = _row_batch(data, rows, n, kind).astype(dtype)
   w = _row_batch(data, rows, n, "random").astype(dtype)
-  with jax.experimental.enable_x64(dtype == np.float64):
+  with jax.enable_x64(dtype == np.float64):
     a = np.asarray(isotonic_kl(jnp.asarray(s), jnp.asarray(w), "scan"))
     b = np.asarray(isotonic_kl(jnp.asarray(s), jnp.asarray(w), "lax"))
   np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)

@@ -81,7 +81,8 @@ def test_bucket_policy_from_plan_splices_breakpoints():
                         max_elems=10**6),
       plan_mod.PlanRule("forward", "scan", min_n=3000),
   ))
-  p = BucketPolicy.from_plan(plan, min_n=64, max_n=4096, max_batch=4)
+  p = BucketPolicy.from_plan(plan, platform="cpu", min_n=64, max_n=4096,
+                             max_batch=4)
   # 100 (a max_n edge) and 2999 (min_n - 1) join the pow2 ladder, so no
   # bucket pads a request across a backend cutoff.
   assert 100 in p.sizes and 2999 in p.sizes
@@ -89,8 +90,23 @@ def test_bucket_policy_from_plan_splices_breakpoints():
   assert p.bucket_for(101) == 128
   # Builtin-plan edges (e.g. the minimax small-n cutoff at 64) are also
   # representable: the chain is consulted when plan=None.
-  assert BucketPolicy.from_plan(None, min_n=8, max_n=128,
+  assert BucketPolicy.from_plan(None, platform="cpu", min_n=8, max_n=128,
                                 max_batch=2).bucket_for(8) <= 64
+
+
+@pytest.mark.parametrize("platform, cpu_edge", [
+    ("cpu", True), ("tpu", False), ("gpu", False)])
+def test_shape_breakpoints_skip_rules_of_other_platforms(platform, cpu_edge):
+  plan = plan_mod.ExecutionPlan(name="edges", rules=(
+      plan_mod.PlanRule("forward", "lax", platform="cpu", max_n=100),
+      plan_mod.PlanRule("forward", "scan", min_n=3000),
+  ))
+  edges = plan_mod.shape_breakpoints(plan, platform=platform)
+  assert 2999 in edges
+  assert (100 in edges) == cpu_edge
+  sizes = BucketPolicy.from_plan(plan, min_n=64, max_n=4096, max_batch=1,
+                                 platform=platform).sizes
+  assert (100 in sizes) == cpu_edge
 
 
 def test_shape_breakpoints_and_resolve_grid():
@@ -98,7 +114,7 @@ def test_shape_breakpoints_and_resolve_grid():
       plan_mod.PlanRule("forward", "minimax", max_n=100, max_elems=10**6),
       plan_mod.PlanRule("forward", "scan"),
   ))
-  edges = plan_mod.shape_breakpoints(plan)
+  edges = plan_mod.shape_breakpoints(plan, platform="cpu")
   assert 100 in edges
   grid = plan_mod.resolve_grid(
       "forward", ["isotonic"], ["l2"], [(4, 32), (4, 4096)],
@@ -372,3 +388,18 @@ def test_bound_op_identity():
       bound_op("soft_rank/l2/desc", "lax", None)
   assert bound_op("soft_rank/l2/desc", "lax", None) is not \
       bound_op("soft_rank/l2/desc", "scan", None)
+
+
+@pytest.mark.parametrize("impl", ["lax", "scan"])
+def test_warmup_compiles_each_cell_once(impl):
+  cfg = EngineConfig(ops=("soft_rank/l2/desc",), min_bucket=8,
+                     max_bucket=16, max_batch=2, impl=impl,
+                     use_plan_buckets=False)
+  eng = ServingEngine(cfg)
+  assert eng.warmup() == 2 * 2   # n-buckets x row-buckets
+  assert eng.warmup() == 0       # every cell already warm
+  assert len(eng.cache) == 4
+  assert metrics.counter_value("aot_cache_warm") == 4
+  res = eng.serve([_req(n) for n in (3, 12)])
+  assert all(r.ok for r in res)
+  assert metrics.counter_value("aot_cache_miss") == 0

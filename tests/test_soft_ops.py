@@ -116,6 +116,19 @@ def test_topk_mask_hard_limit_and_sum():
   assert bool(jnp.all(m2 >= -1e-6)) and bool(jnp.all(m2 <= 1 + 1e-6))
 
 
+@pytest.mark.parametrize("reg", ["l2", "kl"])
+@pytest.mark.parametrize("op", ["soft_topk_mask", "soft_rank"])
+def test_ties_far_from_zero_at_small_eps(op, reg):
+  """x / eps is about 1.7e4 here, where float32 cancellation in z - v
+  would cost the answer its third digit.  Tied inputs have the same
+  result wherever they sit."""
+  fn = {"soft_topk_mask": lambda t: soft_topk_mask(t, 1, 1e-3, reg),
+        "soft_rank": lambda t: soft_rank(t, 1e-3, reg)}[op]
+  far = fn(jnp.full((3,), 17.349, jnp.float32))
+  near = fn(jnp.zeros((3,), jnp.float32))
+  np.testing.assert_allclose(far, near, rtol=0, atol=1e-5)
+
+
 def test_soft_quantile():
   x = jnp.array(rng.normal(size=101).astype(np.float32))
   q = soft_quantile(x, 0.5, 1e-3)

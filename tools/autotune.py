@@ -19,7 +19,7 @@ Derivation policy:
 
 * Rules are keyed to the platform the artifact was measured on; on any
   other platform the packaged plan is silent and resolution falls through
-  to the built-in plan (e.g. TPU -> pallas stays untouched by a CPU-derived
+  to the built-in plan (e.g. TPU -> scan stays untouched by a CPU-derived
   plan).
 * ``pallas`` is excluded as a candidate off-TPU: interpret-mode timings at
   small n say nothing about TPU hardware and extrapolate catastrophically.
