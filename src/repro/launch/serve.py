@@ -33,7 +33,7 @@ import numpy as np
 from repro import plan as repro_plan
 from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import artifacts as obs_artifacts
-from repro.obs.tracing import trace_annotation
+from repro.obs.tracing import build_lines, span
 
 
 def greedy(logits):
@@ -66,15 +66,16 @@ def run_engine(args) -> None:
   print(f"[engine] warmed {compiled} executables over "
         f"{len(engine.policy.sizes)} n-buckets x "
         f"{len(engine.policy.row_sizes)} row-buckets in {t_warm:.1f}s")
+  for line in build_lines():
+    print(f"[build] {line}")
 
   requests = synthetic_stream(
       args.engine_requests, seed=args.engine_seed, ops=ops,
       n_min=args.engine_min_n, n_max=args.engine_max_n,
       deadline_ms=args.engine_deadline_ms)
-  t0 = time.time()
-  with trace_annotation("repro_serve_engine"):
+  with span("repro_serve_engine") as serve_span:
     results = engine.serve(requests)
-  wall = time.time() - t0
+  wall = serve_span.seconds
   ok = [r for r in results if r.ok]
   shed = [r for r in results if not r.ok]
   lat = sorted(r.latency_us for r in ok) if ok else [0.0]
@@ -133,24 +134,24 @@ def run_lm(args) -> None:
   # caches in place instead of copying the full cache pytree per token.
   decode = jax.jit(ST.make_decode_step(cfg), donate_argnums=(1,))
 
-  t0 = time.time()
-  with trace_annotation("repro_serve_prefill"):
+  with span("repro_serve_prefill") as prefill_span:
     logits, caches = prefill(params, batch)
     jax.block_until_ready(logits)
-  t_prefill = time.time() - t0
+  t_prefill = prefill_span.seconds
 
   pos0 = args.prompt_len + (cfg.num_patches if cfg.frontend == "vision"
                             else 0)
   tok = greedy(logits)
   out_tokens = [np.asarray(tok)]
-  t0 = time.time()
-  with trace_annotation("repro_serve_decode"):
+  with span("repro_serve_decode") as decode_span:
     for i in range(args.gen - 1):
       logits, caches = decode(params, caches, tok, jnp.int32(pos0 + i))
       tok = greedy(logits)
       out_tokens.append(np.asarray(tok))
     jax.block_until_ready(logits)
-  t_decode = time.time() - t0
+  t_decode = decode_span.seconds
+  for line in build_lines():
+    print(f"[build] {line}")
 
   gen = np.stack(out_tokens, axis=1)
   print(f"[serve] prefill {args.batch}x{args.prompt_len} in "

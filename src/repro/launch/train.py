@@ -29,7 +29,6 @@ import dataclasses
 import os
 import signal
 import statistics
-import time
 
 import jax
 import jax.numpy as jnp
@@ -43,8 +42,7 @@ from repro.launch import steps as ST
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs import artifacts as obs_artifacts
-from repro.obs import metrics as obs_metrics
-from repro.obs.tracing import trace_annotation
+from repro.obs.tracing import build_lines, span
 from repro.optim import adamw
 from repro.optim.schedule import cosine_with_warmup
 
@@ -81,7 +79,8 @@ class Trainer:
     self.compress_grads = compress_grads
     self.seed = seed
     self._preempted = False
-    self._step_times: list[float] = []
+    self._step_times: list[float] = []   # the repro_train_step spans
+    self._builds_shown = False
     self.straggler_factor = 2.0
     self.straggler_events = 0
 
@@ -129,14 +128,16 @@ class Trainer:
       batch = {k: jnp.asarray(v)
                for k, v in self.pipeline.batch_at(step).items()
                if k != "corrupt_mask"}
-      t0 = time.time()
-      with trace_annotation("repro_train_step"):
+      with span("repro_train_step") as step_span:
         state.params, state.opt_state, metrics = self.train_step(
             state.params, state.opt_state, batch)
         jax.block_until_ready(metrics["loss"])
-      dt = time.time() - t0
-      obs_metrics.observe("train_step_us", dt * 1e6)
+      dt = step_span.seconds
       self.maybe_flag_straggler(dt)
+      if not self._builds_shown:
+        self._builds_shown = True
+        for line in build_lines():
+          print(f"[build] {line}")
       state.step = step + 1
       if step % 10 == 0 or step == state.step - 1:
         print(f"[train] step {step:5d} loss {float(metrics['loss']):.4f} "

@@ -1,11 +1,13 @@
-"""Observability for the dispatch layer: metrics, tracing, timing, artifacts.
+"""Observability: metrics, tracing, timing, artifacts.
 
 This package is the single place the repo records *what actually ran*:
 which backend served each ``(op, regularization)`` dispatch, at what shapes,
-how often jit re-traced, and how long benchmarked calls took.  It exists
-because the paper's headline claim is performance (O(n log n) soft
-sorting/ranking, "an order of magnitude faster" — Blondel et al., 2020) and
-an unverifiable claim is not a reproduction.
+which programs were built and what their trace, lower and compile cost,
+how long the host regions (train steps, serve calls) took, and how long
+benchmarked calls took.  It exists because the paper's headline claim is
+performance (O(n log n) soft sorting/ranking, "an order of magnitude
+faster" — Blondel et al., 2020) and an unverifiable claim is not a
+reproduction.
 
 Modules
 -------
@@ -16,17 +18,21 @@ Modules
     no-op and no state is retained.
 ``repro.obs.tracing``
     ``jax.named_scope`` wrappers so dispatched backend kernels are
-    attributable in jaxprs, HLO and ``jax.profiler`` traces, plus host-side
-    profiler annotations for eager timing regions.
+    attributable in jaxprs, HLO and ``jax.profiler`` traces; ``span``, the
+    one host-side region (profiler annotation + per-thread parent stack +
+    ``span_s`` histogram); and the build listener on ``jax.monitoring``
+    that records each program's trace, lower and compile as ``build_s``
+    (installed here, once per process).
 ``repro.obs.timing``
     Wall-clock timing helpers (``block_until_ready`` walls, median
-    us/call) shared by the benchmark harness and the launch drivers.
+    us/call, nearest-rank percentiles) shared by ``benchmarks/`` and
+    ``repro.launch.serve``.
 ``repro.obs.artifacts``
     The one structured-JSON ``BENCH_*.json`` emitter + schema validator
     used by ``benchmarks/run.py``, ``repro.launch.train`` and
     ``repro.launch.serve`` (schema ``repro.bench/v1``; see
-    docs/BENCHMARKS.md).  ``python -m repro.obs.artifacts FILE...``
-    validates artifacts and is what CI gates the bench smoke on.
+    docs/BENCHMARKS.md).  ``python -m repro.obs FILE...`` validates
+    artifacts and is what CI gates the bench smoke on.
 
 Layering: ``repro.obs`` imports only jax/stdlib — never ``repro.core`` or
 ``repro.kernels`` — so the dispatch layer can depend on it without cycles.
@@ -51,7 +57,9 @@ from repro.obs.metrics import (
     snapshot,
 )
 from repro.obs.timing import time_fn, timed
-from repro.obs.tracing import backend_scope, scope_name, trace_annotation
+from repro.obs.tracing import backend_scope, scope_name, span
+
+tracing.install_build_listener()
 
 __all__ = [
     "SCHEMA_VERSION",
@@ -69,10 +77,10 @@ __all__ = [
     "scope_name",
     "set_enabled",
     "snapshot",
+    "span",
     "time_fn",
     "timed",
     "timing",
-    "trace_annotation",
     "tracing",
     "validate_bench_payload",
     "write_bench_artifact",

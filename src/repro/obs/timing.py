@@ -16,7 +16,7 @@ from typing import Callable
 import jax
 
 from repro.obs import metrics
-from repro.obs.tracing import trace_annotation
+from repro.obs.tracing import span
 
 
 def timed(fn: Callable, *args) -> tuple[object, float]:
@@ -37,7 +37,7 @@ def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 5,
   for _ in range(warmup):
     jax.block_until_ready(fn(*args))
   times = []
-  with trace_annotation(f"repro_bench_{name}" if name else "repro_bench"):
+  with span(f"repro_bench_{name}" if name else "repro_bench"):
     for _ in range(iters):
       _, dt = timed(fn, *args)
       times.append(dt)

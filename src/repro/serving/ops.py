@@ -290,4 +290,8 @@ def bound_op(key: str, impl: str | None = None, plan=None) -> Callable:
   dispatch entries is ``repro.kernels.dispatch.stable_entry``.
   """
   spec = padded_op(key)
-  return functools.partial(spec.fn, impl=impl, plan=plan)
+  fn = functools.partial(spec.fn, impl=impl, plan=plan)
+  # JAX lowers an unnamed partial as ``jit(<unknown>)``; named, its build
+  # records (repro.obs.tracing) name the function it wraps.
+  fn.__name__ = fn.func.__name__
+  return fn
