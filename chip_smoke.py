@@ -20,7 +20,7 @@ One process, three phases, in this order:
   3 steps from random weights made from ``--seed``.  Every loss and
   gradient norm must be finite.
 
-After each phase the ``plan_decide`` counters must show forward ``scan``,
+After each phase the ``plan_decide`` counters must show forward ``dense``,
 backward ``segscan`` and projection ``fused``, and ``pallas`` nowhere.
 
 The script exits non-zero before any phase when JAX finds no TPU, and on
@@ -237,11 +237,11 @@ def _labels(key: str) -> dict[str, str]:
 
 
 def check_routes(phase: str) -> None:
-  """Every decision so far: forward scan, backward segscan, projection
+  """Every decision so far: forward dense, backward segscan, projection
   fused; the scatter reference plan is the only other backward route."""
   from repro.obs import metrics
 
-  want = {"forward": "scan", "backward": "segscan", "projection": "fused"}
+  want = {"forward": "dense", "backward": "segscan", "projection": "fused"}
   decided = metrics.counters("plan_decide")
   check(decided, f"{phase}: no plan_decide counters were recorded")
   for key, count in sorted(decided.items()):
@@ -441,7 +441,7 @@ def phase_trainer(seed: int) -> None:
   delta = _counter_delta(before, metrics.counters("dispatch"))
   lts = {k: v for k, v in delta.items() if "op=projection" in k
          or "op=isotonic" in k}
-  check(any("dispatch_calls{" in k and "backend=scan" in k for k in lts)
+  check(any("dispatch_calls{" in k and "backend=dense" in k for k in lts)
         and any("dispatch_bwd_calls{" in k for k in lts),
         f"trainer: no soft-LTS dispatch in the counters: {delta}")
   times = ", ".join(f"{t:.3f}s" for t in trainer._step_times)

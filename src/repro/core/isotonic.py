@@ -15,6 +15,7 @@ batch dimensions and make exactly one dispatch call per forward pass
 (``repro.kernels.dispatch``), which routes the flattened (rows, n) batch to
 a registered backend — ``"lax"`` (reference ``lax.fori_loop`` stack machine,
 natively batched), ``"scan"`` (log-depth divide-and-conquer PAV),
+``"dense"`` (the same PAV without element-wise gathers; the TPU route),
 ``"pallas"`` (tiled TPU kernel), or ``"minimax"`` (O(n^2) closed form for
 small n / SPMD).  Backend choice follows the unified precedence chain
 (explicit ``impl=`` > ``REPRO_BACKEND`` > execution plan — see

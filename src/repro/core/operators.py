@@ -63,7 +63,7 @@ def soft_sort(
   direction : {"DESCENDING", "ASCENDING"}
       "DESCENDING" (paper primitive) returns values softly sorted from
       largest to smallest; "ASCENDING" is -soft_sort(-values).
-  impl : {"auto", "lax", "scan", "pallas", "minimax"} or None
+  impl : {"auto", "lax", "scan", "dense", "pallas", "minimax"} or None
       Isotonic backend; None defers to the unified precedence chain
       (``repro.kernels.dispatch``). Pass explicitly under jit/grad.
   plan : repro.plan.ExecutionPlan or None
@@ -128,7 +128,7 @@ def soft_rank(
       "DESCENDING" (paper default): rank 1 for the largest value.
       "ASCENDING": rank 1 for the smallest ( = descending rank of
       -theta ).
-  impl : {"auto", "lax", "scan", "pallas", "minimax"} or None
+  impl : {"auto", "lax", "scan", "dense", "pallas", "minimax"} or None
       Isotonic backend; see ``repro.kernels.dispatch``. Pass explicitly
       under jit/grad.
   plan : repro.plan.ExecutionPlan or None
@@ -186,7 +186,7 @@ def soft_rank_kl_direct(
   direction : {"DESCENDING", "ASCENDING"}
       "DESCENDING" (paper default): rank 1 for the largest value;
       "ASCENDING" is the descending variant of -theta.
-  impl : {"auto", "lax", "scan", "pallas", "minimax"} or None
+  impl : {"auto", "lax", "scan", "dense", "pallas", "minimax"} or None
       Isotonic backend (``repro.kernels.dispatch``).
   plan : repro.plan.ExecutionPlan or None
       Pin an execution plan for all of this call's dispatch decisions.
@@ -245,7 +245,7 @@ def soft_topk_mask(
       eps > 0; small eps approaches the hard 0/1 top-k mask.
   regularization : {"l2", "kl"}
       Psi for the projection.
-  impl : {"auto", "lax", "scan", "pallas", "minimax"} or None
+  impl : {"auto", "lax", "scan", "dense", "pallas", "minimax"} or None
       Isotonic backend (``repro.kernels.dispatch``).
   plan : repro.plan.ExecutionPlan or None
       Pin an execution plan for all of this call's dispatch decisions.
@@ -298,7 +298,7 @@ def soft_quantile(
       eps > 0 for the underlying soft sort (Eq. 5).
   regularization : {"l2", "kl"}
       Psi for the projection.
-  impl : {"auto", "lax", "scan", "pallas", "minimax"} or None
+  impl : {"auto", "lax", "scan", "dense", "pallas", "minimax"} or None
       Isotonic backend (``repro.kernels.dispatch``).
   plan : repro.plan.ExecutionPlan or None
       Pin an execution plan for all of this call's dispatch decisions.

@@ -347,7 +347,7 @@ def projection_permutahedron(
   regularization : {"l2", "kl"}
       "l2": Euclidean projection onto P(w). "kl": the paper's log-KL
       projection of e^z onto P(e^w), returned in log space (P_E).
-  impl : {"auto", "lax", "scan", "pallas", "minimax"} or None
+  impl : {"auto", "lax", "scan", "dense", "pallas", "minimax"} or None
       Isotonic backend (``repro.kernels.dispatch``); pass explicitly
       under jit/grad (see ``isotonic_l2`` for why).
   path : {"auto", "fused", "composed"} or None

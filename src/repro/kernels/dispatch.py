@@ -21,6 +21,9 @@ Forward backends
                  log2(n) vectorized merge levels — O(n log n) work at
                  O(log n) depth, the paper's complexity claim realized on
                  depth-dominated hardware (CPU/GPU).
+* ``"dense"``    the same divide-and-conquer PAV with no element-wise
+                 gather (``repro.kernels.pav_dense``), the same merges as
+                 ``scan``: the TPU route, where gathers are slow.
 * ``"pallas"``   tiled kernel (``repro.kernels.pav``); interpret mode
                  off-TPU.  It does not compile for TPU v5e, so no plan
                  routes to it: only an explicit ``impl="pallas"`` does.
@@ -45,7 +48,7 @@ backend, backward backend, projection path)::
         ``set_active_plan`` plan)
       > packaged default plan (src/repro/plan/default_plan.json,
         emitted by tools/autotune.py from measured BENCH sweeps)
-      > built-in plan (repro.plan.builtin_plan: TPU -> scan, small-n
+      > built-in plan (repro.plan.builtin_plan: TPU -> dense, small-n
         minimax under a memory cap, scan otherwise; segscan; fused)
 
 ``"auto"`` — as an argument or environment value — means "fall through
@@ -87,7 +90,7 @@ ENV_VAR = "REPRO_BACKEND"
 BWD_ENV_VAR = "REPRO_BACKWARD"
 PROJECTION_ENV_VAR = "REPRO_PROJECTION"
 
-BACKENDS = ("auto", "lax", "scan", "pallas", "minimax")
+BACKENDS = ("auto", "lax", "scan", "dense", "pallas", "minimax")
 BWD_BACKENDS = ("auto", "segscan", "scatter")
 PROJECTION_PATHS = ("auto", "fused", "composed")
 
@@ -558,6 +561,7 @@ def stable_entry(op: str, regularization: str, backend: str | None = None,
 # ---------------------------------------------------------------------------
 
 from repro.kernels import pav as _pav  # noqa: E402
+from repro.kernels import pav_dense as _pav_dense  # noqa: E402
 from repro.kernels import pav_scan as _pav_scan  # noqa: E402
 from repro.kernels import ref as _ref  # noqa: E402
 from repro.kernels import segment_vjp as _svjp  # noqa: E402
@@ -567,6 +571,9 @@ register("isotonic", "kl", "lax")(_pav.pav_kl_lax)
 
 register("isotonic", "l2", "scan")(_pav_scan.pav_l2_scan)
 register("isotonic", "kl", "scan")(_pav_scan.pav_kl_scan)
+
+register("isotonic", "l2", "dense")(_pav_dense.pav_l2_dense)
+register("isotonic", "kl", "dense")(_pav_dense.pav_kl_dense)
 
 register("isotonic", "l2", "pallas")(_pav.pav_l2)
 register("isotonic", "kl", "pallas")(_pav.pav_kl)

@@ -31,7 +31,7 @@ The *active* plan is installed per-process (:func:`set_active_plan`, the
 by ``tools/autotune.py`` from measured ``BENCH_*.json`` sweeps (every
 rule carries the timing-row names that justify it — validated in CI by
 ``tools/check_backends.py --plan``); the *built-in* plan is the
-shape-oblivious safety net (TPU -> scan, small-n -> minimax under a
+shape-oblivious safety net (TPU -> dense, small-n -> minimax under a
 memory cap, otherwise scan; segscan backward; fused projection) and is
 total — some rule always matches.
 
@@ -271,9 +271,10 @@ def load_plan(path: str) -> ExecutionPlan:
 def builtin_plan() -> ExecutionPlan:
   """The constants-derived fallback plan, matching every possible query.
 
-  TPU -> the log-depth ``scan`` machine at every shape (the ``pallas``
-  PAV kernel does not compile for v5e and is reachable only through an
-  explicit ``impl="pallas"``); off-TPU the O(n^2) ``minimax`` closed form
+  TPU -> the gather-free ``dense`` divide-and-conquer PAV at every shape
+  (element-wise gathers are slow there; the ``pallas`` PAV kernel does
+  not compile for v5e and is reachable only through an explicit
+  ``impl="pallas"``); off-TPU the O(n^2) ``minimax`` closed form
   for small n under its memory cap, ``scan`` otherwise (including all
   shapeless queries); ``segscan`` backward; ``fused`` projection.
   """
@@ -283,7 +284,7 @@ def builtin_plan() -> ExecutionPlan:
 _BUILTIN = ExecutionPlan(
     name="builtin",
     rules=(
-        PlanRule("forward", "scan", op="isotonic", platform="tpu"),
+        PlanRule("forward", "dense", op="isotonic", platform="tpu"),
         PlanRule("forward", "minimax", op="isotonic",
                  max_n=BUILTIN_MINIMAX_MAX_N,
                  max_elems=BUILTIN_MINIMAX_MAX_ELEMS),

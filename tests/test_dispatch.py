@@ -23,7 +23,7 @@ BATCHED_SHAPES = [(9,), (2, 3, 17)]
 def test_registry_contains_all_backends_for_both_regs():
   for reg in ("l2", "kl"):
     have = set(D.registered_backends("isotonic", reg))
-    assert {"lax", "scan", "pallas", "minimax"} <= have
+    assert {"lax", "scan", "dense", "pallas", "minimax"} <= have
 
 
 def test_backward_registry_contains_both_formulations():
@@ -37,11 +37,11 @@ def test_auto_resolution_is_deterministic_per_platform():
   # the platform it was measured on (cpu: lax for small-n few-row and
   # huge-n huge-batch cells, scan everywhere in between; see
   # src/repro/plan/default_plan.json) and from the built-in plan
-  # everywhere else (tpu -> scan at every shape; gpu is unmeasured ->
+  # everywhere else (tpu -> dense at every shape; gpu is unmeasured ->
   # builtin chain: minimax under its small-n cap, scan beyond).
   for platform, shape, want in [
-      ("tpu", (4, 9), "scan"),
-      ("tpu", (256, 4096), "scan"),
+      ("tpu", (4, 9), "dense"),
+      ("tpu", (256, 4096), "dense"),
       ("cpu", (4, 9), "lax"),
       ("cpu", (4, D.AUTO_MINIMAX_MAX_N + 1), "lax"),
       ("cpu", (1_000_000, 64), "scan"),
@@ -66,7 +66,7 @@ def test_shapeless_auto_resolution_never_picks_minimax():
     assert D.resolve_backend("isotonic", "l2", None, shape=None,
                              platform=platform) == "scan"
   assert D.resolve_backend("isotonic", "kl", None, shape=None,
-                           platform="tpu") == "scan"
+                           platform="tpu") == "dense"
 
 
 def test_explicit_backend_wins_over_default():
@@ -107,10 +107,10 @@ def test_isotonic_l2_lax_vs_pallas_fwd_and_vjp(shape):
   y = jnp.array(rng.normal(size=shape).astype(np.float32))
   u = jnp.array(rng.normal(size=shape).astype(np.float32))
   outs, grads = {}, {}
-  for b in ("lax", "scan", "pallas", "minimax"):
+  for b in ("lax", "scan", "dense", "pallas", "minimax"):
     outs[b] = isotonic_l2(y, b)
     grads[b] = jax.grad(lambda t: jnp.sum(isotonic_l2(t, b) * u))(y)
-  for b in ("scan", "pallas", "minimax"):
+  for b in ("scan", "dense", "pallas", "minimax"):
     np.testing.assert_allclose(outs[b], outs["lax"], atol=1e-5)
     np.testing.assert_allclose(grads[b], grads["lax"], atol=1e-5)
 
@@ -123,11 +123,11 @@ def test_isotonic_kl_lax_vs_pallas_fwd_and_vjp(shape):
                 jnp.float32)
   u = jnp.array(rng.normal(size=shape).astype(np.float32))
   outs, gss, gws = {}, {}, {}
-  for b in ("lax", "scan", "pallas", "minimax"):
+  for b in ("lax", "scan", "dense", "pallas", "minimax"):
     outs[b] = isotonic_kl(s, w, b)
     gss[b], gws[b] = jax.grad(
         lambda a, c: jnp.sum(isotonic_kl(a, c, b) * u), argnums=(0, 1))(s, w)
-  for b in ("scan", "pallas", "minimax"):
+  for b in ("scan", "dense", "pallas", "minimax"):
     np.testing.assert_allclose(outs[b], outs["lax"], atol=5e-5)
     np.testing.assert_allclose(gss[b], gss["lax"], atol=5e-5)
     np.testing.assert_allclose(gws[b], gws["lax"], atol=5e-5)
@@ -150,7 +150,7 @@ def test_soft_ops_backends_agree_end_to_end(reg, shape):
   op = soft_rank
   f_lax = loss(theta, "lax", op)
   g_lax = jax.grad(lambda t: loss(t, "lax", op))(theta)
-  for b in ("scan", "pallas", "minimax"):
+  for b in ("scan", "dense", "pallas", "minimax"):
     np.testing.assert_allclose(loss(theta, b, op), f_lax, atol=1e-5)
     np.testing.assert_allclose(
         jax.grad(lambda t: loss(t, b, op))(theta), g_lax, atol=1e-5)
@@ -189,7 +189,7 @@ def test_vjp_matches_finite_difference_batched_all_backends():
   eps = 1e-3
   # pallas omitted: its VJP is literally the same backward function (only
   # forwards differ), and grad equality to lax is asserted above.
-  for b in ("lax", "scan", "minimax"):
+  for b in ("lax", "scan", "dense", "minimax"):
     f = lambda t: jnp.sum(isotonic_l2(t, b) * u)
     g = jax.grad(f)(y)
     fd = np.zeros((2, 5), np.float32)
@@ -296,12 +296,12 @@ def test_half_dtype_contract_uniform_across_backends(half):
   wh = jnp.broadcast_to(w32.astype(half), xh.shape)
 
   outs_l2, outs_kl = {}, {}
-  for backend in ("lax", "scan", "minimax"):
+  for backend in ("lax", "scan", "dense", "minimax"):
     o2 = D.dispatch("isotonic", "l2", backend, xh)
     ok = D.dispatch("isotonic", "kl", backend, xh, wh)
     assert o2.dtype == half and ok.dtype == half, backend
     outs_l2[backend], outs_kl[backend] = o2, ok
-  for backend in ("scan", "minimax"):
+  for backend in ("scan", "dense", "minimax"):
     np.testing.assert_allclose(
         np.asarray(outs_l2[backend], np.float32),
         np.asarray(outs_l2["lax"], np.float32), rtol=2e-2, atol=2e-2)

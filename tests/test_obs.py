@@ -56,10 +56,10 @@ def test_plan_decide_counter_labels_kind_source_and_plan():
   c = metrics.counters("plan_decide")
   # cpu routes through the committed autotuned default plan (small-n,
   # few-row cells measure fastest on lax); tpu is not measured there,
-  # so it falls through to the builtin tpu -> scan rule.
+  # so it falls through to the builtin tpu -> dense rule.
   assert c["plan_decide{backend=lax,kind=forward,"
            "plan=autotuned-cpu,source=default_plan}"] == 1
-  assert c["plan_decide{backend=scan,kind=forward,"
+  assert c["plan_decide{backend=dense,kind=forward,"
            "plan=builtin,source=builtin}"] == 1
   assert c["plan_decide{backend=lax,kind=forward,"
            "plan=pinned,source=plan}"] == 1

@@ -33,7 +33,7 @@ except ImportError:
 
 rng = np.random.default_rng(17)
 
-BACKENDS = ["lax", "scan", "minimax"]
+BACKENDS = ["lax", "scan", "dense", "minimax"]
 REGS = ["l2", "kl"]
 BUCKET = 16
 

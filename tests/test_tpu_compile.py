@@ -16,6 +16,7 @@ imported would hold its lock in every test worker.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import core
 from repro.core.losses import soft_trimmed_token_loss
+from repro.kernels import dispatch
 from repro.obs import metrics
 from repro.serving.ops import bound_op
 
@@ -105,11 +107,25 @@ def test_compiles_for_v5e_on_the_default_route(case, one_chip, on_tpu):
   assert "tpu_custom_call" not in compiled.as_text()
   mem = compiled.memory_analysis()
   assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2**30
-  # It resolved as the builtin TPU rules say: scan / segscan / fused.
+  # It resolved as the builtin TPU rules say: dense / segscan / fused.
   decided = metrics.counters("plan_decide")
-  want = {"forward": "scan", "backward": "segscan", "projection": "fused"}
+  want = {"forward": "dense", "backward": "segscan", "projection": "fused"}
   assert decided
   for key in decided:
     labels = dict(kv.split("=", 1)
                   for kv in key[key.index("{") + 1:-1].split(","))
     assert labels["backend"] == want[labels["kind"]], key
+
+
+@pytest.mark.parametrize("shape", [(1024, 256), (1, 131072)])
+def test_isotonic_solve_holds_no_gather_on_v5e(shape, one_chip, on_tpu):
+  """The TPU route solves without element-wise gathers (each costs about
+  10 ns an element there), and the long row's temporaries stay small."""
+  spec = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+  solve = lambda y: dispatch.dispatch("isotonic", "l2", None, y)
+  compiled = jax.jit(solve).lower(spec).compile()
+  text = compiled.as_text()
+  assert "repro_isotonic_l2_dense" in text
+  assert not re.findall(r"= \S+ gather\(", text)
+  if shape == (1, 131072):
+    assert compiled.memory_analysis().temp_size_in_bytes <= 512 * 2**20
