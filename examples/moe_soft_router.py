@@ -28,27 +28,21 @@ def make_cfg(router: str) -> ArchConfig:
       name=f"moe-{router}", family="moe", num_layers=4, d_model=128,
       num_heads=4, num_kv_heads=2, head_dim=32, d_ff=256, vocab_size=4096,
       block_cycle=("moe",), num_experts=8, experts_per_token=2,
-      moe_d_ff=128, router=router, router_eps=1.0, moe_group_size=64,
+      moe_d_ff=128, router=router, router_eps=1.0,
       dtype="float32", remat="none", q_chunk=64, kv_chunk=64,
       xent_chunk=64)
 
 
 def expert_load_cv(cfg, params, batch):
-  """Coefficient of variation of expert dispatch counts (balance metric)."""
+  """Coefficient of variation of expert assignment counts (balance
+  metric)."""
   from repro.models import layers as L
-  from repro.models.moe import _dispatch_mask, _router_weights
+  from repro.models.moe import route
   x, _ = T._embed_inputs(cfg, params, batch)
-  lp = params["seg0"]["l0_moe"]
-  h = L.norm_apply(jax.tree.map(lambda a: a[0], lp["norm1"]), x, cfg.norm)
-  xt = h.reshape(-1, cfg.d_model)
-  xg = xt.reshape(-1, cfg.moe_group_size, cfg.d_model)
-  router = lp["ffn"]["router"][0]
-  logits = jnp.einsum("gtd,de->gte", xg, router)
-  w, _ = _router_weights(cfg, logits)
-  capacity = int(np.ceil(cfg.moe_group_size * cfg.experts_per_token *
-                         cfg.capacity_factor / cfg.num_experts))
-  dispatch, _ = _dispatch_mask(w, cfg.experts_per_token, capacity)
-  loads = jnp.sum(dispatch, axis=(0, 1, 3))
+  lp = jax.tree.map(lambda a: a[0], params["seg0"]["l0_moe"])
+  h = L.norm_apply(lp["norm1"], x, cfg.norm)
+  ids, _, _ = route(lp["ffn"], h.reshape(-1, cfg.d_model), cfg)
+  loads = jnp.sum(jax.nn.one_hot(ids, cfg.num_experts), axis=(0, 1))
   return float(jnp.std(loads) / jnp.maximum(jnp.mean(loads), 1e-9))
 
 

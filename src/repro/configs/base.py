@@ -13,6 +13,21 @@ from typing import Callable
 
 _REGISTRY: dict[str, "ArchConfig"] = {}
 
+# The dense layer kind that leads a cycle of this kind.
+_DENSE_TWIN = {"moe": "dense", "mla_moe": "mla_dense"}
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+  """YaRN rope scaling, as a HF ``rope_scaling`` of type ``yarn`` holds
+  it (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+  factor: float
+  original_max_position: int
+  beta_fast: float = 32.0
+  beta_slow: float = 1.0
+  mscale: float = 1.0
+  mscale_all_dim: float = 0.0
+
 
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
@@ -26,10 +41,13 @@ class ArchConfig:
   d_ff: int
   vocab_size: int
 
-  # Block pattern: cycle of layer kinds, applied as kind[i % len(cycle)].
-  # Kinds: dense | local | moe | local_moe | mla_dense | mla_moe | rg |
+  # Block pattern: cycle of layer kinds, applied as kind[i % len(cycle)]
+  # after ``first_dense_layers`` leading layers of the cycle's dense twin
+  # (moe -> dense, mla_moe -> mla_dense).
+  # Kinds: dense | global | local | moe | mla_dense | mla_moe | rg |
   #        mlstm | slstm
   block_cycle: tuple[str, ...] = ("dense",)
+  first_dense_layers: int = 0     # deepseek's first_k_dense_replace
   window_size: int = 0            # sliding window for "local" layers
 
   # MoE
@@ -39,8 +57,11 @@ class ArchConfig:
   moe_d_ff: int = 0
   router: str = "softmax_topk"    # softmax_topk | soft_topk (paper)
   router_eps: float = 1.0
-  capacity_factor: float = 1.25
-  moe_group_size: int = 512       # routing-group tokens (bounds dispatch cost)
+  norm_topk_prob: bool = True     # renormalise the chosen experts' weights
+  # This chip's share of the experts: ``experts_held`` of them (0 = all),
+  # ids first_held_expert .. first_held_expert + experts_held - 1.
+  experts_held: int = 0
+  first_held_expert: int = 0
 
   # MLA (deepseek)
   kv_lora_rank: int = 0
@@ -56,6 +77,7 @@ class ArchConfig:
   mlp_variant: str = "swiglu"     # swiglu | geglu | gelu
   norm: str = "rmsnorm"           # rmsnorm | layernorm
   rope_theta: float = 10000.0
+  rope_yarn: Yarn | None = None   # YaRN rope scaling (deepseek)
   tie_embeddings: bool = False
   logit_softcap: float = 0.0
 
@@ -86,21 +108,32 @@ class ArchConfig:
   def attn_dim(self) -> int:
     return self.num_heads * self.head_dim
 
+  @property
+  def num_experts_held(self) -> int:
+    return self.experts_held or self.num_experts
+
   def layer_kinds(self) -> list[str]:
     cyc = self.block_cycle
-    return [cyc[i % len(cyc)] for i in range(self.num_layers)]
+    lead = ([_DENSE_TWIN[cyc[0]]] * self.first_dense_layers
+            if self.first_dense_layers else [])
+    return lead + [cyc[i % len(cyc)]
+                   for i in range(self.num_layers - len(lead))]
 
   def plan_segments(self) -> list[tuple[tuple[str, ...], int]]:
     """Group layers into (cycle, repeats) segments for lax.scan stacking.
 
-    The full cycle is scanned ``num_layers // len(cycle)`` times; any
+    Leading dense layers form the first segment.  The full cycle is then
+    scanned ``(num_layers - first_dense_layers) // len(cycle)`` times; any
     remainder layers form a trailing unrolled segment (repeats=1 each
     sub-cycle so params still stack uniformly).
     """
     kinds = self.layer_kinds()
+    segments: list[tuple[tuple[str, ...], int]] = []
+    if self.first_dense_layers:
+      segments.append(((kinds[0],), self.first_dense_layers))
+    kinds = kinds[self.first_dense_layers:]
     cyc = tuple(self.block_cycle)
     reps = len(kinds) // len(cyc)
-    segments: list[tuple[tuple[str, ...], int]] = []
     if reps > 0:
       segments.append((cyc, reps))
     rem = kinds[reps * len(cyc):]

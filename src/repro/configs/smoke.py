@@ -17,7 +17,8 @@ def smoke_config(name: str) -> ArchConfig:
   cycle_len = len(cfg.block_cycle)
   reductions = dict(
       name=f"{cfg.name}-smoke",
-      num_layers=2 * cycle_len if cycle_len > 1 else 2,
+      num_layers=(cfg.first_dense_layers
+                  + (2 * cycle_len if cycle_len > 1 else 2)),
       d_model=64,
       num_heads=4,
       num_kv_heads=min(cfg.num_kv_heads, 2) if cfg.num_kv_heads < cfg.num_heads
@@ -29,7 +30,6 @@ def smoke_config(name: str) -> ArchConfig:
       xent_chunk=16,
       q_chunk=16,
       kv_chunk=16,
-      moe_group_size=32,
       grad_accum=1,
       dtype="float32",
       remat="none",

@@ -21,9 +21,13 @@ from repro.optim.compression import ef_int8_roundtrip
 Array = jax.Array
 
 
+# The MoE counters a step returns beside its loss (``T.forward_train``).
+MOE_METRICS = ("moe_assignments_held", "moe_busiest_share")
+
+
 def loss_from_batch(cfg, params, batch) -> tuple[Array, dict[str, Array]]:
   with jax.named_scope("repro_forward_train"):
-    token_losses, aux = T.forward_train(cfg, params, batch)
+    token_losses, stats = T.forward_train(cfg, params, batch)
   if cfg.loss_trim_fraction > 0:
     # Paper §6.4 at LM scale: soft least-trimmed-squares over per-token
     # losses, applied per sequence (bounded PAV length; DESIGN.md §4).
@@ -33,8 +37,11 @@ def loss_from_batch(cfg, params, batch) -> tuple[Array, dict[str, Array]]:
           cfg.loss_trim_fraction, cfg.loss_trim_eps))
   else:
     loss = jnp.mean(token_losses)
-  total = loss + 0.01 * aux
-  return total, {"loss": loss, "aux_loss": aux}
+  aux = stats["aux_loss"]
+  metrics = {"loss": loss, "aux_loss": aux}
+  if cfg.num_experts:
+    metrics.update({k: stats[k] for k in MOE_METRICS})
+  return loss + 0.01 * aux, metrics
 
 
 def make_train_step(cfg, opt_cfg: adamw.AdamWConfig, *,
@@ -61,19 +68,21 @@ def make_train_step(cfg, opt_cfg: adamw.AdamWConfig, *,
       mbatches = micro(batch)
 
       def body(carry, mb):
-        gsum, lsum = carry
+        gsum, msum = carry
         (_, metrics), g = grads_of(params, mb)
         gsum = jax.tree.map(
             lambda a, b: a + b.astype(a.dtype), gsum, g)
-        return (gsum, lsum + metrics["loss"]), None
+        msum = T.add_stats(msum, metrics)
+        return (gsum, msum), None
 
       acc_dt = jnp.dtype(getattr(cfg, "grad_accum_dtype", "float32"))
       gzero = jax.tree.map(
           lambda p: jnp.zeros(p.shape, acc_dt), params)
-      (gsum, lsum), _ = jax.lax.scan(
-          body, (gzero, jnp.zeros((), jnp.float32)), mbatches)
+      keys = ("loss",) + (MOE_METRICS if cfg.num_experts else ())
+      mzero = {k: jnp.zeros((), jnp.float32) for k in keys}
+      (gsum, metrics), _ = jax.lax.scan(body, (gzero, mzero), mbatches)
       grads = jax.tree.map(lambda g: (g / accum).astype(cfg.dtype), gsum)
-      metrics = {"loss": lsum / accum,
+      metrics = {**metrics, "loss": metrics["loss"] / accum,
                  "aux_loss": jnp.zeros((), jnp.float32)}
     else:
       (_, metrics), grads = grads_of(params, batch)

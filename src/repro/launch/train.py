@@ -42,6 +42,7 @@ from repro.launch import steps as ST
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs import artifacts as obs_artifacts
+from repro.obs import metrics as obs_metrics
 from repro.obs.tracing import build_lines, span
 from repro.optim import adamw
 from repro.optim.schedule import cosine_with_warmup
@@ -117,6 +118,16 @@ class Trainer:
         print(f"[train] straggler step: {dt*1e3:.0f} ms vs median "
               f"{med*1e3:.0f} ms (event #{self.straggler_events})")
 
+  def record_moe(self, metrics):
+    """The MoE counters the step returned: the assignments computed by
+    the held experts, and the busiest held expert's share of them."""
+    if "moe_assignments_held" in metrics:
+      obs_metrics.counter_inc("moe_assignments",
+                              int(metrics["moe_assignments_held"]),
+                              where="held")
+      obs_metrics.observe("moe_busiest_share",
+                          float(metrics["moe_busiest_share"]))
+
   # -- main loop ----------------------------------------------------------
 
   def run(self, state: TrainerState, num_steps: int):
@@ -134,6 +145,7 @@ class Trainer:
         jax.block_until_ready(metrics["loss"])
       dt = step_span.seconds
       self.maybe_flag_straggler(dt)
+      self.record_moe(metrics)
       if not self._builds_shown:
         self._builds_shown = True
         for line in build_lines():

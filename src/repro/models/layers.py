@@ -18,6 +18,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.sharding.specs import shard_activation
@@ -57,19 +58,55 @@ def norm_apply(p: Params, x: Array, kind: str, eps: float = 1e-6) -> Array:
 # ---------------------------------------------------------------------------
 
 
-def rope(x: Array, positions: Array, theta: float) -> Array:
-  """x: (..., S, H, D) or (..., H, D) w/ scalar positions; rotate pairs."""
+def rope(x: Array, positions: Array, theta: float, yarn=None) -> Array:
+  """x: (..., S, H, D) or (..., H, D) w/ scalar positions; rotate pairs
+  (feature i with i + D/2).  ``yarn`` (a ``configs.base.Yarn``) rescales
+  the frequencies and the rotation as YaRN does."""
   d = x.shape[-1]
   half = d // 2
-  freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+  if yarn is None:
+    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+  else:
+    freq = jnp.asarray(yarn_inv_freq(d, theta, yarn))
   ang = positions[..., None].astype(jnp.float32) * freq  # (..., S, half)
   cos, sin = jnp.cos(ang), jnp.sin(ang)
+  if yarn is not None:
+    mscale = (yarn_get_mscale(yarn.factor, yarn.mscale)
+              / yarn_get_mscale(yarn.factor, yarn.mscale_all_dim))
+    if mscale != 1.0:
+      cos, sin = cos * mscale, sin * mscale
   cos = cos[..., None, :]  # broadcast over heads
   sin = sin[..., None, :]
   x1, x2 = x[..., :half], x[..., half:]
   out = jnp.concatenate(
       [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
   return out.astype(x.dtype)
+
+
+def yarn_get_mscale(factor: float, mscale: float) -> float:
+  """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+  return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+  """YaRN inverse frequencies of a ``dim``-wide rotary embedding (float32,
+  ``dim // 2`` of them): the original frequencies where a dimension turns
+  more than ``beta_fast`` times over the original context, ``1/factor`` of
+  them where it turns fewer than ``beta_slow`` times, a linear ramp
+  between (DeepSeek-V2's ``yarn_find_correction_range``)."""
+  def correction_dim(rotations):
+    return (dim * math.log(yarn.original_max_position
+                           / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+  low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+  high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
+  if low == high:
+    high += 0.001
+  extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+  inter = extra / yarn.factor
+  ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                 / (high - low), 0, 1)
+  return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -144,18 +181,24 @@ def flash_attention(
     kv_chunk: int = 1024,
     softcap: float = 0.0,
     q_offset: int | Array = 0,
+    scale: float | None = None,
+    remat_q_blocks: bool = False,
 ) -> Array:
   """Chunked attention. q: (B,Sq,H,D); k,v: (B,Skv,Hkv,D) -> (B,Sq,H,D).
 
   `q_offset`: global position of q[0] relative to k[0] (prefill continuation
   / decode). With `window > 0` only kv blocks inside the window are visited
   (O(S*W)); otherwise all kv blocks are scanned with causal masking.
+  `scale` multiplies the scores (default 1/sqrt(D)).  With
+  `remat_q_blocks` each query block is recomputed in the backward pass,
+  which then holds one block's probabilities instead of every block's.
   """
   b, sq, h, d = q.shape
   _, skv, hkv, _ = k.shape
   dv = v.shape[-1]          # may differ from d (MLA: un-padded values)
   g = h // hkv
-  scale = 1.0 / math.sqrt(d)
+  if scale is None:
+    scale = 1.0 / math.sqrt(d)
   q_chunk = min(q_chunk, sq)
   kv_chunk = min(kv_chunk, skv)
   while sq % q_chunk:
@@ -213,7 +256,8 @@ def flash_attention(
     out = one_q_block(0, qg)
   else:
     qs = qg.reshape(b, nq, q_chunk, hkv, g, d).transpose(1, 0, 2, 3, 4, 5)
-    out = lax.map(lambda args: one_q_block(args[0], args[1]),
+    block = jax.checkpoint(one_q_block) if remat_q_blocks else one_q_block
+    out = lax.map(lambda args: block(args[0], args[1]),
                   (jnp.arange(nq), qs))
     out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, hkv, g, dv)
   return out.reshape(b, sq, h, dv)

@@ -11,6 +11,8 @@ Supported layer kinds:
   dense / global   GQA attention + MLP
   local            sliding-window GQA attention + MLP
   moe              GQA attention + MoE FFN (+ shared experts)
+  mla_dense        Multi-head Latent Attention + MLP (deepseek's leading
+                   dense layers)
   mla_moe          Multi-head Latent Attention + MoE FFN (deepseek)
   rg               RG-LRU recurrent block + MLP (recurrentgemma)
   mlstm / slstm    xLSTM blocks + MLP
@@ -36,6 +38,23 @@ from repro.sharding.specs import shard_activation
 Array = jax.Array
 Params = dict[str, Any]
 
+_ATTN_KINDS = ("dense", "global", "local", "moe")
+_MLA_KINDS = ("mla_dense", "mla_moe")
+_MOE_KINDS = ("moe", "mla_moe")
+# What every layer adds to the step's statistics (MoE layers fill them):
+# summed over layers, except the busiest share, the largest of any layer.
+_STAT_KEYS = ("aux_loss", "moe_assignments_held", "moe_busiest_share")
+
+
+def _no_stats():
+  return {k: jnp.zeros((), jnp.float32) for k in _STAT_KEYS}
+
+
+def add_stats(a, b):
+  """a's statistics merged with b's: summed, the busiest share maxed."""
+  return {k: (jnp.maximum if k == "moe_busiest_share" else jnp.add)(
+      a[k], b[k]) for k in a}
+
 
 def _dtype(cfg):
   return jnp.dtype(cfg.dtype)
@@ -50,7 +69,7 @@ def _ffn_variant(cfg, kind) -> str:
 
 
 def _ffn_init(key, cfg, kind, dtype):
-  if kind in ("moe", "mla_moe"):
+  if kind in _MOE_KINDS:
     return MOE.moe_init(key, cfg, dtype)
   if kind == "mlstm":
     f = 2 * cfg.d_model
@@ -62,9 +81,9 @@ def _ffn_init(key, cfg, kind, dtype):
 
 
 def _mixer_init(key, cfg, kind, dtype):
-  if kind in ("dense", "global", "local", "moe"):
+  if kind in _ATTN_KINDS:
     return {"attn": L.attn_init(key, cfg, dtype)}
-  if kind == "mla_moe":
+  if kind in _MLA_KINDS:
     return {"mla": MLA.mla_init(key, cfg, dtype)}
   if kind == "rg":
     return {"rg": RG.rg_init(key, cfg, dtype)}
@@ -92,10 +111,10 @@ def _window(cfg, kind) -> int:
 
 
 def _layer_apply_seq(p, x, positions, cfg, kind, *, collect_cache=False):
-  """Returns (x, aux, cache_or_None)."""
+  """Returns (x, stats, cache_or_None)."""
   h = L.norm_apply(p["norm1"], x, cfg.norm)
   cache = None
-  if kind in ("dense", "global", "local", "moe"):
+  if kind in _ATTN_KINDS:
     if collect_cache:
       mixed, (kc, vc) = L.attn_apply_seq(
           p["attn"], h, positions, cfg, window=_window(cfg, kind),
@@ -104,7 +123,7 @@ def _layer_apply_seq(p, x, positions, cfg, kind, *, collect_cache=False):
     else:
       mixed = L.attn_apply_seq(
           p["attn"], h, positions, cfg, window=_window(cfg, kind))
-  elif kind == "mla_moe":
+  elif kind in _MLA_KINDS:
     if collect_cache:
       mixed, cache = MLA.mla_apply_seq(
           p["mla"], h, positions, cfg, return_kv=True)
@@ -131,23 +150,23 @@ def _layer_apply_seq(p, x, positions, cfg, kind, *, collect_cache=False):
   x = shard_activation(x, "residual")
 
   h2 = L.norm_apply(p["norm2"], x, cfg.norm)
-  aux = jnp.zeros((), jnp.float32)
-  if kind in ("moe", "mla_moe"):
-    ff, aux = MOE.moe_apply(p["ffn"], h2, cfg)
+  stats = _no_stats()
+  if kind in _MOE_KINDS:
+    ff, stats = MOE.moe_apply(p["ffn"], h2, cfg)
   else:
     ff = L.mlp_apply(p["ffn"], h2, _ffn_variant(cfg, kind))
   x = x + ff.astype(x.dtype)
   x = shard_activation(x, "residual")
-  return x, aux, cache
+  return x, stats, cache
 
 
 def _layer_apply_decode(p, x, cache, pos, cfg, kind):
   """x: (B, d). Returns (x, new_cache)."""
   h = L.norm_apply(p["norm1"], x, cfg.norm)
-  if kind in ("dense", "global", "local", "moe"):
+  if kind in _ATTN_KINDS:
     mixed, cache = L.attn_apply_decode(
         p["attn"], h, cache, pos, cfg, window=_window(cfg, kind))
-  elif kind == "mla_moe":
+  elif kind in _MLA_KINDS:
     mixed, cache = MLA.mla_apply_decode(p["mla"], h, cache, pos, cfg)
   elif kind == "rg":
     mixed, cache = RG.rg_apply_decode(p["rg"], h, cache, cfg)
@@ -160,7 +179,7 @@ def _layer_apply_decode(p, x, cache, pos, cfg, kind):
   x = x + mixed.astype(x.dtype)
 
   h2 = L.norm_apply(p["norm2"], x, cfg.norm)
-  if kind in ("moe", "mla_moe"):
+  if kind in _MOE_KINDS:
     ff, _ = MOE.moe_apply(p["ffn"], h2, cfg)
   else:
     ff = L.mlp_apply(p["ffn"], h2, _ffn_variant(cfg, kind))
@@ -170,12 +189,10 @@ def _layer_apply_decode(p, x, cache, pos, cfg, kind):
 
 
 def _layer_init_cache(cfg, kind, batch, max_len, dtype):
-  if kind in ("dense", "global", "local", "moe"):
-    win = _window(cfg, kind)
-    length = min(max_len, win + 8) if win else max_len
+  if kind in _ATTN_KINDS:
     # window caches could be ring buffers; keep full length for simplicity
     return L.attn_init_cache(cfg, batch, max_len, dtype)
-  if kind == "mla_moe":
+  if kind in _MLA_KINDS:
     return MLA.mla_init_cache(cfg, batch, max_len, dtype)
   if kind == "rg":
     return RG.rg_init_state(cfg, batch, dtype)
@@ -244,24 +261,24 @@ def _embed_inputs(cfg, params, batch) -> tuple[Array, Array]:
 
 
 def _run_segments(cfg, params, x, positions, *, collect_caches=False):
-  """Scan all segments. Returns (x, aux_total, caches|None)."""
-  aux_total = jnp.zeros((), jnp.float32)
+  """Scan all segments. Returns (x, stats, caches|None)."""
+  stats = _no_stats()
   caches: list[Any] = []
 
   for si, (cycle, reps) in enumerate(cfg.plan_segments()):
     seg_params = params[f"seg{si}"]
 
     def seg_body(carry, layer_params, cycle=cycle):
-      x, aux = carry
+      x, stats = carry
       cache_out = {}
       for j, kind in enumerate(cycle):
-        x, a, c = _layer_apply_seq(
+        x, st, c = _layer_apply_seq(
             layer_params[f"l{j}_{kind}"], x, positions, cfg, kind,
             collect_cache=collect_caches)
-        aux = aux + a
+        stats = add_stats(stats, st)
         if collect_caches:
           cache_out[f"l{j}_{kind}"] = c
-      return (x, aux), cache_out if collect_caches else None
+      return (x, stats), cache_out if collect_caches else None
 
     if cfg.remat == "full":
       seg_body = jax.checkpoint(
@@ -271,15 +288,17 @@ def _run_segments(cfg, params, x, positions, *, collect_caches=False):
       seg_body = jax.checkpoint(
           seg_body, policy=jax.checkpoint_policies.checkpoint_dots)
 
-    (x, aux_total), seg_caches = lax.scan(
-        seg_body, (x, aux_total), seg_params)
+    (x, stats), seg_caches = lax.scan(seg_body, (x, stats), seg_params)
     caches.append(seg_caches)
 
-  return x, aux_total, caches if collect_caches else None
+  return x, stats, caches if collect_caches else None
 
 
-def forward_train(cfg, params, batch) -> tuple[Array, Array]:
-  """Per-token NLL (B, S_target) + aux loss scalar."""
+def forward_train(cfg, params, batch) -> tuple[Array, dict[str, Array]]:
+  """Per-token NLL (B, S_target) + the step's statistics: ``aux_loss``
+  (the MoE load-balance loss, summed over layers),
+  ``moe_assignments_held`` (summed over layers) and ``moe_busiest_share``
+  (the largest of any layer); all zero without MoE layers."""
   x, positions = _embed_inputs(cfg, params, batch)
   x, aux, _ = _run_segments(cfg, params, x, positions)
   x = L.norm_apply(params["final_norm"], x, cfg.norm)

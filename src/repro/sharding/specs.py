@@ -104,7 +104,8 @@ PARAM_RULES: list[tuple[str, tuple[Any, ...]]] = [
     (r".*mla/w_uv$", (None, TP, None)),            # (r, H, v)
     (r".*mla/wo$", (TP, None, FSDP)),              # (H, v, d)
     (r".*ffn/router$", None),                      # (d, E) replicated
-    (r".*ffn/we_in$", (TP, FSDP, "__MOE_FF__")),   # (E, d, f): EP over model
+    (r".*ffn/we_in$", (TP, FSDP, "__MOE_FF__")),   # (E, d, f): E over model
+                                                   # (refused by moe.py)
     (r".*ffn/we_gate$", (TP, FSDP, "__MOE_FF__")),
     (r".*ffn/we_out$", (TP, "__MOE_FF__", FSDP)),  # (E, f, d)
     (r".*ffn/(shared/)?w_in$", (FSDP, TP)),        # (d, f) dense/shared MLP
@@ -263,13 +264,6 @@ def current_rules() -> ShardingRules | None:
 
 # Activation kinds -> wanted axes (resolved lazily, divisibility-checked).
 _ACT_RULES: dict[str, tuple[Any, ...]] = {
-    "moe_groups": (DP, None, None),            # (G, gs, d): groups over DP
-    "moe_router": (DP, TP, None),              # (G, gs, E): router math is
-                                               # per-token -> split gs over
-                                               # model (bounds the O(E^2)
-                                               # projection workspace)
-    "moe_groups4": (DP, TP, None, None),       # (G, E, cap, d): E aligned
-                                               # with model-sharded experts
     "residual": (DP, "__SEQ__", None),         # (B, S, d)
     "residual_decode": (DP, None),             # (B, d)
     "heads": (DP, None, TP, None),             # (B, S, H, Dh)
@@ -278,8 +272,6 @@ _ACT_RULES: dict[str, tuple[Any, ...]] = {
     "kv_cache_batch": (DP, None, None, None),  # alt: batch-only
     "logits": (DP, None, TP),                  # (B, S, V)
     "logits_decode": (DP, TP),                 # (B, V)
-    "expert_acts": (TP, None, None),           # (E, cap, d)
-    "expert_acts4": (DP, TP, None, None),      # (G, E, cap, d)
     "ffn": (DP, None, TP),                     # (B, S, f)
     "rg_state": (DP, TP),                      # (B, lru)
     "mlstm_state": (DP, None, None, TP),       # (B, H, dk, dv)

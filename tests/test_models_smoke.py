@@ -49,7 +49,7 @@ def test_forward_and_grad(arch):
   params = T.init_params(cfg, key)
   batch = make_batch(cfg, key)
 
-  losses, aux = jax.jit(lambda p, b: T.forward_train(cfg, p, b))(
+  losses, stats = jax.jit(lambda p, b: T.forward_train(cfg, p, b))(
       params, batch)
   tgt = batch["targets"]
   expect = tgt.shape[:2]
@@ -60,7 +60,7 @@ def test_forward_and_grad(arch):
 
   def scalar_loss(p):
     l, a = T.forward_train(cfg, p, batch)
-    return jnp.mean(l) + 0.01 * a
+    return jnp.mean(l) + 0.01 * a["aux_loss"]
 
   g = jax.jit(jax.grad(scalar_loss))(params)
   assert all(bool(jnp.all(jnp.isfinite(x))) for x in jax.tree.leaves(g))
@@ -96,8 +96,8 @@ def test_decode_matches_full_forward(arch):
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", ["deepseek-v2-lite-16b", "grok-1-314b"])
 def test_moe_decode_matches_with_lossless_capacity(arch):
+  # The MoE layer is dropless: no capacity to raise.
   cfg = smoke_config(arch)
-  cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
   key = jax.random.PRNGKey(1)
   params = T.init_params(cfg, key)
   b, s = 2, 16
@@ -129,7 +129,7 @@ def test_soft_topk_router_vs_softmax_router_gradients():
 
     def loss(p):
       l, a = T.forward_train(c, p, batch)
-      return jnp.mean(l) + 0.01 * a
+      return jnp.mean(l) + 0.01 * a["aux_loss"]
 
     g = jax.grad(loss)(params)
     flat = jax.tree_util.tree_flatten_with_path(g)[0]
