@@ -21,7 +21,7 @@ One process, three phases, in this order:
   gradient norm must be finite.
 
 After each phase the ``plan_decide`` counters must show forward ``dense``,
-backward ``segscan`` and projection ``fused``, and ``pallas`` nowhere.
+backward ``segscan`` and projection ``sortperm``, and ``pallas`` nowhere.
 
 The script exits non-zero before any phase when JAX finds no TPU, and on
 any failed check.  Times are printed for information only.  The last line
@@ -238,10 +238,11 @@ def _labels(key: str) -> dict[str, str]:
 
 def check_routes(phase: str) -> None:
   """Every decision so far: forward dense, backward segscan, projection
-  fused; the scatter reference plan is the only other backward route."""
+  sortperm; the scatter reference plan is the only other backward route."""
   from repro.obs import metrics
 
-  want = {"forward": "dense", "backward": "segscan", "projection": "fused"}
+  want = {"forward": "dense", "backward": "segscan",
+          "projection": "sortperm"}
   decided = metrics.counters("plan_decide")
   check(decided, f"{phase}: no plan_decide counters were recorded")
   for key, count in sorted(decided.items()):

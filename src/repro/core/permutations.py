@@ -27,6 +27,20 @@ ordered by their (distinct) bit patterns rather than treated as equal keys
 — numerically irrelevant downstream, where equal values merge into one
 isotonic block anyway.
 
+Permuting by sorting (TPU)
+--------------------------
+TPU has no 64-bit integers, and an element-wise gather there costs about
+10 ns an element — far more than a sort of the same row.  Sorting the
+pairs ``(p_k, x_k)`` by the distinct int32 keys ``p`` leaves ``x_k`` at
+position ``p_k``, i.e. it computes ``x[p^{-1}]`` as exact data movement:
+``permute_by_sort`` does that for any number of payloads, and
+``sort_descending_with_perm`` yields the sorted values and sigma from one
+stable key-value sort.  The TPU projection route (``"sortperm"`` in
+``repro.core.projection``) applies every permutation this way, and gets
+sigma^{-1} as a payload of its un-permute, so it never inverts a
+permutation; ``invert_permutation_fast`` (for ``SortContext``) inverts
+past the u32 pack limit with one such sort.
+
 Staging caveat: the packed fast path must NOT be traced inside a
 ``jax.custom_vjp`` body.  Lowering a custom_vjp sub-jaxpr with global x64
 off re-canonicalizes the size-changing u32 -> u64 bitcast into a
@@ -63,6 +77,32 @@ def argsort_descending(x: Array, axis: int = -1) -> Array:
 def argsort_ascending(x: Array, axis: int = -1) -> Array:
   return jnp.argsort(lax.stop_gradient(x), axis=axis,
                      stable=True).astype(_INT)
+
+
+def sort_descending_with_perm(x: Array) -> tuple[Array, Array]:
+  """(x sorted descending, sigma int32) along the last axis, detached.
+
+  One stable key-value sort of ``(-x, iota)`` — exactly the sort
+  ``argsort_descending`` builds, with the same tie order — whose keys are
+  the sorted values (negation is exact), so no gather reads them back.
+  """
+  x = lax.stop_gradient(x)
+  iota = lax.broadcasted_iota(_INT, x.shape, x.ndim - 1)
+  neg, sigma = lax.sort((-x, iota), dimension=-1, is_stable=True,
+                        num_keys=1)
+  return -neg, sigma
+
+
+def permute_by_sort(keys: Array, *operands: Array) -> tuple[Array, ...]:
+  """Each operand moved so that ``out[..., keys[k]] = x[..., k]``.
+
+  ``keys`` is a permutation along the last axis (int32, the operands'
+  shape), so one unstable key-value sort does it exactly:
+  ``x[..., p]`` is ``permute_by_sort(p^{-1}, x)``, ``x[..., p^{-1}]`` is
+  ``permute_by_sort(p, x)``, and an iota payload returns ``p^{-1}``.
+  """
+  return tuple(lax.sort((keys, *operands), dimension=-1, is_stable=False,
+                        num_keys=1)[1:])
 
 
 def sort_descending(x: Array) -> tuple[Array, Array]:
@@ -142,15 +182,14 @@ def argsort_descending_fast(x: Array) -> tuple[Array, Array]:
   """(sorted values, sigma int32) descending along the last axis.
 
   Single u64 integer sort on f32/CPU/GPU (~4x faster than the comparator
-  argsort at n=1024); falls back to ``sort_descending`` semantics (under
-  ``stop_gradient``) for other dtypes/platforms.  Non-differentiable: both
+  argsort at n=1024); falls back to ``sort_descending_with_perm`` for
+  other dtypes/platforms.  Non-differentiable: both
   outputs are detached — callers on the fused projection path own their
   gradients.
   """
   x = lax.stop_gradient(x)
   if not _fast_sort_ok(x):
-    sigma = argsort_descending(x)
-    return jnp.take_along_axis(x, sigma, axis=-1), sigma
+    return sort_descending_with_perm(x)
   n = x.shape[-1]
   keys = _f32_total_order_keys(x, descending=True)
   iota = jnp.broadcast_to(jnp.arange(n, dtype=jnp.uint32), x.shape)
@@ -162,12 +201,14 @@ def invert_permutation_fast(sigma: Array) -> Array:
   """sigma^{-1} (int32) without a scatter: one packed integer sort.
 
   For n <= 65535 the (position-in-sorted-order, original-index) pair packs
-  into a single u32 key (``sigma * n + iota``); larger n (or TPU, which
-  has no u64) uses the u64 pack / an explicit scatter respectively.
+  into a single u32 key (``sigma * n + iota``); larger n uses the u64
+  pack, or on TPU (no u64) a key-value sort keyed by sigma.
   """
   n = sigma.shape[-1]
   if jax.default_backend() == "tpu" and n > _U32_INVERT_MAX_N:
-    return inverse_permutation(sigma)
+    sigma = sigma.astype(_INT)
+    return permute_by_sort(
+        sigma, lax.broadcasted_iota(_INT, sigma.shape, sigma.ndim - 1))[0]
   iota = jnp.broadcast_to(jnp.arange(n, dtype=jnp.uint32), sigma.shape)
   sig_u = sigma.astype(jnp.uint32)
   if n <= _U32_INVERT_MAX_N:

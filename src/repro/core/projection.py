@@ -3,13 +3,14 @@
   P_Psi(z, w) = z - v_Psi(z_sigma(z), sort_desc(w))_{sigma^{-1}(z)}
 
 `P(w)` is permutation-invariant in `w`, so `w` need not be sorted by the
-caller.  Two registered pipelines compute it (dispatch registry keys
+caller.  Three registered pipelines compute it (dispatch registry keys
 ``("projection", regularization, path)``, selected by
 ``repro.kernels.dispatch.resolve_projection`` through the unified chain —
-explicit ``path=`` > env ``REPRO_PROJECTION`` > execution plan; every
-built-in plan resolves to ``"fused"``):
+explicit ``path=`` > env ``REPRO_PROJECTION`` > execution plan; the
+built-in plan resolves to ``"sortperm"`` on TPU and ``"fused"``
+elsewhere):
 
-``"fused"`` (default)
+``"fused"`` (CPU, GPU)
     The whole pipeline is ONE ``jax.custom_vjp``: packed single-key
     integer sorts (``repro.core.permutations.argsort_descending_fast`` /
     ``invert_permutation_fast`` — the XLA integer-sort fast path, ~4x
@@ -26,6 +27,17 @@ built-in plan resolves to ``"fused"``):
     pay for one argsort.  Unbatched *concrete* weights hit a small
     process-level sorted-``w`` cache, so eager eps sweeps never re-sort
     the same weight vector.
+
+``"sortperm"`` (TPU)
+    The same custom VJP (``by_sort``), with every permutation a key-value
+    ``lax.sort`` that carries the data instead of an element-wise gather
+    (about 10 ns an element on TPU, where a sort of the row costs far
+    less): one stable sort yields the sorted values and sigma, the
+    un-permute is a sort keyed by sigma that also returns sigma^{-1} as
+    its iota payload, and the backward's two permutations and the weight
+    gradient's are sorts keyed by sigma^{-1}, sigma and tau.  The keys
+    are distinct, so the values are bitwise those a gather would read;
+    no permutation is ever inverted, so nothing scatters at any n.
 
 ``"composed"``
     The reference chain of four differentiable primitives — descending
@@ -54,11 +66,12 @@ from jax import lax
 from repro.core.isotonic import isotonic_kl, isotonic_l2
 from repro.core.permutations import (
     apply_inverse_permutation,
-    argsort_descending,
     argsort_descending_fast,
     inverse_permutation,
     invert_permutation_fast,
+    permute_by_sort,
     sort_descending,
+    sort_descending_with_perm,
 )
 from repro.kernels import dispatch as _dispatch
 from repro.kernels import segment_vjp as _svjp
@@ -143,15 +156,25 @@ def _sorted_w_unbatched(ws: Array) -> tuple[Array, Array, Array]:
 # ---------------------------------------------------------------------------
 
 
-def _fused_forward(regularization, impl, plan, z_is_sorted, w_is_sorted,
-                   z, w, z_perm, w_perm):
+def _permute(x, perm, perm_inv, by_sort):
+  """``x[..., perm]``: a gather reading ``perm``, or (``by_sort``) one
+  key-value sort keyed by ``perm^{-1}`` — the same values, bitwise."""
+  if by_sort:
+    return permute_by_sort(perm_inv, x)[0]
+  return jnp.take_along_axis(x, perm, axis=-1)
+
+
+def _fused_forward(regularization, impl, plan, by_sort, z_is_sorted,
+                   w_is_sorted, z, w, z_perm, w_perm):
   """Shared primal: returns (out, residuals).
 
   This function is staged *inside* the custom_vjp, where the packed u64
-  sort fast path miscompiles (see ``_fused_entry``), so the permutation
-  fallbacks below use the safe comparator sorts.  Dispatch callers never
-  hit them: ``_fused_entry`` precomputes ``z_perm`` / ``w_perm`` with the
-  fast path in the surrounding trace context.
+  sort fast path miscompiles (see ``_fused_entry``), so the sorts below
+  are comparator key-value sorts.  ``fused`` dispatch callers never hit
+  them: ``_fused_entry`` precomputes ``z_perm`` / ``w_perm`` with the
+  fast path in the surrounding trace context.  ``sortperm`` sorts here,
+  and with ``by_sort`` every permutation is a sort that carries the data
+  (``repro.core.permutations.permute_by_sort``), never a gather.
   """
   n = z.shape[-1]
   zs = lax.stop_gradient(z)
@@ -159,25 +182,25 @@ def _fused_forward(regularization, impl, plan, z_is_sorted, w_is_sorted,
     s, sigma, sigma_inv = zs, None, None
   elif z_perm is not None:
     sigma, sigma_inv = z_perm
-    s = jnp.take_along_axis(zs, sigma, axis=-1)
+    s = _permute(zs, sigma, sigma_inv, by_sort)
   else:
-    sigma = argsort_descending(zs)
-    s = jnp.take_along_axis(zs, sigma, axis=-1)
-    sigma_inv = inverse_permutation(sigma)
+    s, sigma = sort_descending_with_perm(zs)
+    # by_sort: sigma^{-1} comes out of the un-permute below.
+    sigma_inv = None if by_sort else inverse_permutation(sigma)
 
   ws = lax.stop_gradient(w)
   if ws.ndim > 1 and ws.shape != z.shape:
     ws = jnp.broadcast_to(ws, z.shape)
-  tau_inv = None
+  tau = tau_inv = None
   if w_is_sorted:
     w_sorted = ws
   elif w_perm is not None:
     tau, tau_inv = w_perm
-    w_sorted = jnp.take_along_axis(ws, tau, axis=-1)
+    w_sorted = _permute(ws, tau, tau_inv, by_sort)
   else:
-    tau = argsort_descending(ws)
-    w_sorted = jnp.take_along_axis(ws, tau, axis=-1)
-    tau_inv = inverse_permutation(tau)
+    w_sorted, tau = sort_descending_with_perm(ws)
+    if not by_sort:
+      tau_inv = inverse_permutation(tau)
 
   if regularization == "l2":
     y = s - w_sorted                       # broadcasts unbatched w_sorted
@@ -193,9 +216,18 @@ def _fused_forward(regularization, impl, plan, z_is_sorted, w_is_sorted,
   start_idx = start_idx.reshape(v.shape)
   end_idx = end_idx.reshape(v.shape)
 
-  out = z - (v if sigma_inv is None else
-             jnp.take_along_axis(v, sigma_inv, axis=-1))
-  res = (sigma, sigma_inv, tau_inv, starts, start_idx, end_idx,
+  if sigma is None:
+    v_out = v
+  elif sigma_inv is None:
+    # One sort keyed by sigma returns v_{sigma^{-1}} and, from an iota
+    # payload, sigma^{-1} itself for the backward pass.
+    v_out, sigma_inv = permute_by_sort(
+        sigma, v, lax.broadcasted_iota(sigma.dtype, sigma.shape,
+                                       sigma.ndim - 1))
+  else:
+    v_out = _permute(v, sigma_inv, sigma, by_sort)
+  out = z - v_out
+  res = (sigma, sigma_inv, tau, tau_inv, starts, start_idx, end_idx,
          s if regularization == "kl" else None, w_b, lax.stop_gradient(w))
   return out, res
 
@@ -220,31 +252,33 @@ def _perm_cotangent(perm):
       lambda a: np.zeros(np.shape(a), jax.dtypes.float0), perm)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
-def _fused_projection(regularization, impl, plan, z_is_sorted, w_is_sorted,
-                      z, w, z_perm, w_perm):
-  return _fused_forward(regularization, impl, plan, z_is_sorted,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
+def _fused_projection(regularization, impl, plan, by_sort, z_is_sorted,
+                      w_is_sorted, z, w, z_perm, w_perm):
+  return _fused_forward(regularization, impl, plan, by_sort, z_is_sorted,
                         w_is_sorted, z, w, z_perm, w_perm)[0]
 
 
-def _fused_fwd(regularization, impl, plan, z_is_sorted, w_is_sorted,
-               z, w, z_perm, w_perm):
-  out, res = _fused_forward(regularization, impl, plan, z_is_sorted,
-                            w_is_sorted, z, w, z_perm, w_perm)
+def _fused_fwd(regularization, impl, plan, by_sort, z_is_sorted,
+               w_is_sorted, z, w, z_perm, w_perm):
+  out, res = _fused_forward(regularization, impl, plan, by_sort,
+                            z_is_sorted, w_is_sorted, z, w, z_perm, w_perm)
   return out, res + (z_perm, w_perm)
 
 
-def _fused_bwd(regularization, impl, plan, z_is_sorted, w_is_sorted, res, g):
-  """Whole-pipeline VJP from saved residuals: gather -> segmented
-  reduction (Lemma 2, dispatched backward table) -> gather.  No re-sort,
-  no scatter."""
+def _fused_bwd(regularization, impl, plan, by_sort, z_is_sorted,
+               w_is_sorted, res, g):
+  """Whole-pipeline VJP from saved residuals: permute -> segmented
+  reduction (Lemma 2, dispatched backward table) -> permute.  No re-sort
+  of the data, no scatter; each permutation is a gather, or with
+  ``by_sort`` a sort keyed by the other of (sigma, sigma^{-1})."""
   del impl, z_is_sorted
-  (sigma, sigma_inv, tau_inv, starts, start_idx, end_idx, s, w_b, w_orig,
-   z_perm, w_perm) = res
+  (sigma, sigma_inv, tau, tau_inv, starts, start_idx, end_idx, s, w_b,
+   w_orig, z_perm, w_perm) = res
 
-  # d out / d v is -I composed with the sigma^{-1} gather: permute the
-  # cotangent into sorted order.
-  g_v = -(g if sigma is None else jnp.take_along_axis(g, sigma, axis=-1))
+  # d out / d v is -I composed with the sigma^{-1} un-permute: permute
+  # the cotangent into sorted order.
+  g_v = -(g if sigma is None else _permute(g, sigma, sigma_inv, by_sort))
   if regularization == "l2":
     g_y = _dispatch.dispatch_backward("projection", "l2", None,
                                       g_v, starts, start_idx, end_idx,
@@ -256,19 +290,19 @@ def _fused_bwd(regularization, impl, plan, z_is_sorted, w_is_sorted, res, g):
                                             start_idx, end_idx, plan=plan)
 
   # z cotangent: identity term plus the solve term mapped back through
-  # sigma^{-1} (a gather — sigma^{-1} is already a residual).
-  g_z = g + (g_s if sigma_inv is None else
-             jnp.take_along_axis(g_s, sigma_inv, axis=-1))
+  # sigma^{-1} (both permutations are already residuals).
+  g_z = g + (g_s if sigma is None else
+             _permute(g_s, sigma_inv, sigma, by_sort))
 
-  # w cotangent: back from sorted order via tau^{-1} (gather), then
+  # w cotangent: back from sorted order through tau^{-1}, then
   # un-broadcast (sum) onto the original weight shape.
   if w_orig.ndim == 1:
     g_w = _unbroadcast(g_ws, w_orig.shape)
-    if tau_inv is not None:
-      g_w = jnp.take_along_axis(g_w, tau_inv, axis=-1)
+    if not w_is_sorted:
+      g_w = _permute(g_w, tau_inv, tau, by_sort)
   else:
-    if tau_inv is not None:
-      g_ws = jnp.take_along_axis(g_ws, tau_inv, axis=-1)
+    if not w_is_sorted:
+      g_ws = _permute(g_ws, tau_inv, tau, by_sort)
     g_w = _unbroadcast(g_ws, w_orig.shape)
   return g_z, g_w, _perm_cotangent(z_perm), _perm_cotangent(w_perm)
 
@@ -309,13 +343,32 @@ def _fused_entry(regularization: str, z: Array, w: Array, impl: str | None,
       _, tau = argsort_descending_fast(ws)
       tau_inv = invert_permutation_fast(tau)
     w_perm = (tau, tau_inv)
-  return _fused_projection(regularization, impl, plan, bool(z_is_sorted),
-                           bool(w_is_sorted), z, w, z_perm, w_perm)
+  return _fused_projection(regularization, impl, plan, False,
+                           bool(z_is_sorted), bool(w_is_sorted), z, w,
+                           z_perm, w_perm)
+
+
+def _sortperm_entry(regularization: str, z: Array, w: Array,
+                    impl: str | None, plan=None, *,
+                    z_is_sorted: bool = False, w_is_sorted: bool = False,
+                    z_perm=None, w_perm=None) -> Array:
+  """The fused pipeline with every permutation a sort carrying the data.
+
+  Nothing is precomputed: the comparator sorts are safe inside the
+  custom_vjp, and each yields the values it permutes (sorted z and sigma
+  from one sort, v_{sigma^{-1}} and sigma^{-1} from another), so no
+  gather reads them back and no permutation is inverted.
+  """
+  return _fused_projection(regularization, impl, plan, True,
+                           bool(z_is_sorted), bool(w_is_sorted),
+                           jnp.asarray(z), w, z_perm, w_perm)
 
 
 for _reg in _REGS:
   _dispatch.register("projection", _reg, "fused")(
       functools.partial(_fused_entry, _reg))
+  _dispatch.register("projection", _reg, "sortperm")(
+      functools.partial(_sortperm_entry, _reg))
   _dispatch.register("projection", _reg, "composed")(
       functools.partial(_composed_projection, _reg))
 
@@ -350,9 +403,10 @@ def projection_permutahedron(
   impl : {"auto", "lax", "scan", "dense", "pallas", "minimax"} or None
       Isotonic backend (``repro.kernels.dispatch``); pass explicitly
       under jit/grad (see ``isotonic_l2`` for why).
-  path : {"auto", "fused", "composed"} or None
+  path : {"auto", "fused", "sortperm", "composed"} or None
       Pipeline selection; None defers to env ``REPRO_PROJECTION`` then
-      the execution-plan chain (plans resolve to ``"fused"``).
+      the execution-plan chain (the built-in plan resolves to
+      ``"sortperm"`` on TPU, ``"fused"`` elsewhere).
   plan : repro.plan.ExecutionPlan or None
       Pin an execution plan for every decision this call makes (forward
       backend, backward backend, projection path).  Rides the fused
@@ -365,7 +419,8 @@ def projection_permutahedron(
   z_perm, w_perm : (sigma, sigma^{-1}) int32 pairs or None
       Precomputed descending-argsort permutations for the respective
       argument (e.g. from ``repro.core.permutations.SortContext``) —
-      the fused path replaces its packed sorts with two gathers.
+      the fused path replaces its packed sorts with two gathers
+      (``sortperm``: with two sorts keyed by the inverses).
 
   Returns
   -------

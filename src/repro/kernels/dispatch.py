@@ -49,7 +49,8 @@ backend, backward backend, projection path)::
       > packaged default plan (src/repro/plan/default_plan.json,
         emitted by tools/autotune.py from measured BENCH sweeps)
       > built-in plan (repro.plan.builtin_plan: TPU -> dense, small-n
-        minimax under a memory cap, scan otherwise; segscan; fused)
+        minimax under a memory cap, scan otherwise; segscan; TPU ->
+        sortperm, fused otherwise)
 
 ``"auto"`` — as an argument or environment value — means "fall through
 to the plan chain".  Resolution is deterministic given (request,
@@ -92,7 +93,7 @@ PROJECTION_ENV_VAR = "REPRO_PROJECTION"
 
 BACKENDS = ("auto", "lax", "scan", "dense", "pallas", "minimax")
 BWD_BACKENDS = ("auto", "segscan", "scatter")
-PROJECTION_PATHS = ("auto", "fused", "composed")
+PROJECTION_PATHS = ("auto", "fused", "sortperm", "composed")
 
 # Backwards-compatible aliases for the (former) hardcoded auto cutoffs;
 # the authoritative values now live in the built-in plan
@@ -367,6 +368,8 @@ def resolve_projection(
   on ``repro.core.projection`` import) holds whole-pipeline
   implementations: ``"fused"`` — single custom VJP around sort + isotonic
   solve + gather, packed integer sorts, gather-only backward;
+  ``"sortperm"`` — the same custom VJP with every permutation a key-value
+  sort that carries the data, no gather (the TPU route);
   ``"composed"`` — the reference chain of four differentiable primitives,
   kept reachable (env/plan ``composed``) for differential testing.
   """
@@ -422,7 +425,7 @@ def dispatch_projection(z: Array, w: Array, regularization: str,
                         impl: str | None, path: str | None = None,
                         plan: ExecutionPlan | None = None,
                         **kwargs) -> Array:
-  """Route a permutahedron projection to the fused or composed pipeline.
+  """Route a permutahedron projection to its registered pipeline.
 
   Unlike ``dispatch``, implementations here own their batching (the fused
   path needs the unflattened unbatched-``w`` shape to share one weight
@@ -592,8 +595,8 @@ register_backward("isotonic", "kl", "scatter")(_svjp.isotonic_kl_bwd_scatter)
 # Fused-projection backward table: same Lemma 2 segment algebra, consuming
 # the block structure precomputed by the fused forward (residuals) instead
 # of re-deriving it from the solver output.  Forward projection paths
-# ("fused" / "composed") register themselves on ``repro.core.projection``
-# import — kernels must not import core.
+# ("fused" / "sortperm" / "composed") register themselves on
+# ``repro.core.projection`` import — kernels must not import core.
 register_backward("projection", "l2",
                   "segscan")(_svjp.projection_l2_bwd_segscan)
 register_backward("projection", "l2",

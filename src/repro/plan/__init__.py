@@ -11,7 +11,7 @@ in one serializable, hashable object:
   ``(kind, op, regularization, platform, dtype, shape-bucket)`` regime to
   a concrete backend, where ``kind`` is one of ``"forward"`` (isotonic
   solver), ``"backward"`` (Lemma-2 VJP formulation) or ``"projection"``
-  (fused vs composed pipeline);
+  (fused, sortperm or composed pipeline);
 * JSON round-tripping under schema ``repro.plan/v1`` with strict
   unknown-field and version-mismatch rejection, so a committed plan file
   can be trusted byte-for-byte;
@@ -32,8 +32,8 @@ by ``tools/autotune.py`` from measured ``BENCH_*.json`` sweeps (every
 rule carries the timing-row names that justify it — validated in CI by
 ``tools/check_backends.py --plan``); the *built-in* plan is the
 shape-oblivious safety net (TPU -> dense, small-n -> minimax under a
-memory cap, otherwise scan; segscan backward; fused projection) and is
-total — some rule always matches.
+memory cap, otherwise scan; segscan backward; TPU -> sortperm projection,
+fused elsewhere) and is total — some rule always matches.
 
 This module is deliberately light: stdlib + ``repro.obs.metrics`` only
 (no jax), so tools can load and validate plans without pulling in the
@@ -276,7 +276,9 @@ def builtin_plan() -> ExecutionPlan:
   not compile for v5e and is reachable only through an explicit
   ``impl="pallas"``); off-TPU the O(n^2) ``minimax`` closed form
   for small n under its memory cap, ``scan`` otherwise (including all
-  shapeless queries); ``segscan`` backward; ``fused`` projection.
+  shapeless queries); ``segscan`` backward; the ``sortperm`` projection
+  on TPU (every permutation a sort carrying the data: gathers are slow
+  there, sorts are not), ``fused`` elsewhere.
   """
   return _BUILTIN
 
@@ -290,6 +292,7 @@ _BUILTIN = ExecutionPlan(
                  max_elems=BUILTIN_MINIMAX_MAX_ELEMS),
         PlanRule("forward", "scan", op="isotonic"),
         PlanRule("backward", "segscan"),
+        PlanRule("projection", "sortperm", op="projection", platform="tpu"),
         PlanRule("projection", "fused", op="projection"),
     ),
 )

@@ -58,6 +58,25 @@ def test_auto_resolution_is_deterministic_per_platform():
     assert got == [want] * 3, (platform, shape, got)
 
 
+def test_builtin_plan_routes_projection_by_platform(monkeypatch):
+  # TPU permutes by sorting payloads (gathers are slow there); CPU and
+  # GPU keep the fused gathers and packed integer sorts.
+  for platform, want in [("tpu", "sortperm"), ("cpu", "fused"),
+                         ("gpu", "fused")]:
+    for shape in [None, (1024, 256), (8192, 64), (1, 131072)]:
+      got = D.resolve_projection(None, "l2", shape=shape, platform=platform)
+      assert got == want, (platform, shape, got)
+  # The environment and an explicit path= still override the plan.
+  monkeypatch.setenv(D.PROJECTION_ENV_VAR, "fused")
+  assert D.resolve_projection(None, "l2", platform="tpu") == "fused"
+  monkeypatch.setenv(D.PROJECTION_ENV_VAR, "sortperm")
+  assert D.resolve_projection(None, "kl", platform="cpu") == "sortperm"
+  assert D.resolve_projection("composed", "l2", platform="tpu") == "composed"
+  monkeypatch.delenv(D.PROJECTION_ENV_VAR)
+  assert D.resolve_projection("fused", "l2", platform="tpu") == "fused"
+  assert D.resolve_projection("sortperm", "l2", platform="cpu") == "sortperm"
+
+
 def test_shapeless_auto_resolution_never_picks_minimax():
   """Regression: shape=None used to read as n=0, satisfying the small-n
   test and silently routing arbitrarily large problems to the O(n^2)

@@ -5,9 +5,8 @@ it for a v5e that is described, not attached, so whatever the chip's
 compiler refuses fails here at no chip time.  ``python chip_smoke.py``
 runs the same path on the chip itself.
 
-The code takes its TPU branches (the comparator argsort, the scatter
-inverse at large n, the serving engine's route labels) from
-``jax.default_backend()``, which answers "cpu" during such a compile, so
+The code takes its TPU branches (the comparator argsort, the serving
+engine's route labels) from ``jax.default_backend()``, which answers "cpu" during such a compile, so
 every case steers that answer to "tpu".  The topology is described only
 inside a fixture: a module that touched the TPU library while being
 imported would hold its lock in every test worker.
@@ -107,9 +106,10 @@ def test_compiles_for_v5e_on_the_default_route(case, one_chip, on_tpu):
   assert "tpu_custom_call" not in compiled.as_text()
   mem = compiled.memory_analysis()
   assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2**30
-  # It resolved as the builtin TPU rules say: dense / segscan / fused.
+  # It resolved as the builtin TPU rules say: dense / segscan / sortperm.
   decided = metrics.counters("plan_decide")
-  want = {"forward": "dense", "backward": "segscan", "projection": "fused"}
+  want = {"forward": "dense", "backward": "segscan",
+          "projection": "sortperm"}
   assert decided
   for key in decided:
     labels = dict(kv.split("=", 1)
@@ -129,3 +129,35 @@ def test_isotonic_solve_holds_no_gather_on_v5e(shape, one_chip, on_tpu):
   assert not re.findall(r"= \S+ gather\(", text)
   if shape == (1, 131072):
     assert compiled.memory_analysis().temp_size_in_bytes <= 512 * 2**20
+
+
+def _topk(shape, k):
+  fn = lambda t: core.soft_topk_mask(t, k, 1.0)
+  return _fwd_grad(fn), [shape, shape], [jnp.float32, jnp.float32]
+
+
+# The three benchmark cells' operator programs, forward and gradient.
+PERMUTATION_CASES = {
+    "soft_rank-1024x256": lambda: _operator("soft_rank", "l2", (1024, 256)),
+    "soft_topk_mask-8192x64-k6": lambda: _topk((8192, 64), 6),
+    "soft_lts-grad-131072": lambda: _soft_lts(131072),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PERMUTATION_CASES))
+def test_projection_holds_no_permutation_gather_on_v5e(case, one_chip,
+                                                       on_tpu):
+  """On the TPU route every permutation is a sort that carries the data:
+  the only gathers left are the backward's block-end reads (under a
+  ``_bwd_`` scope), and nothing scatters (no inverse permutation)."""
+  fn, shapes, dtypes = PERMUTATION_CASES[case]()
+  structs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in zip(shapes, dtypes)]
+  text = jax.jit(fn).lower(*structs).compile().as_text()
+  assert "repro_projection_l2_sortperm" in text
+  moves = [line for line in text.splitlines()
+           if re.search(r"= \S+ (gather|scatter)\(", line)]
+  assert moves
+  for line in moves:
+    op_name = re.search(r'op_name="([^"]*)"', line)
+    assert op_name and "_bwd_" in op_name.group(1), line
